@@ -31,6 +31,8 @@ def main() -> None:
                     help="perf smoke -> BENCH_decode.json + BENCH_serving.json"
                          " + BENCH_ffn.json, then exit (the CI trend records)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.smoke:
         from benchmarks.decode_bench import run_smoke

@@ -302,6 +302,7 @@ sys.path.insert(0, "src")
 import jax
 import numpy as np
 from repro.configs import get_smoke_config
+from repro.launch.mesh import make_mesh
 from repro.models import api
 from repro.parallel.hints import use_mesh
 from repro.serving.engine import Engine, Request
@@ -353,7 +354,7 @@ def trial(cfg, batch, ctx):
     }
 
 single = trial(cfg_one, 4, contextlib.nullcontext())
-mesh = jax.make_mesh((1, n_dev), ("data", "model"))
+mesh = make_mesh((1, n_dev), ("data", "model"))
 sharded = trial(cfg_mesh, 16, use_mesh(mesh))
 tokens_equal = single.pop("outputs") == sharded.pop("outputs")
 print("RESULT " + json.dumps({
@@ -371,7 +372,7 @@ def run_mesh() -> dict:
     """Sharded paged serving vs a single device at EQUAL per-device KV
     budget (the PR 10 acceptance cut).
 
-    Runs in a subprocess with 8 forced host devices: the single-device
+    Runs in a CPU subprocess with 8 forced host devices: the single-device
     engine gets ``P_DEV + 1`` pool rows; the sharded engine gets the same
     rows on EACH of the 8 shards (block homes), so resident batch scales
     with total mesh memory.  Token streams must be identical — the mesh
@@ -379,10 +380,12 @@ def run_mesh() -> dict:
     import os
     import subprocess
     import sys
+    # a CPU capacity count: the child is pinned to the host platform, so it
+    # never competes for an accelerator the parent process already holds
     proc = subprocess.run(
         [sys.executable, "-c", _MESH_WORKER], capture_output=True, text=True,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        timeout=900)
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), timeout=900)
     for line in proc.stdout.splitlines():
         if line.startswith("RESULT "):
             return json.loads(line[len("RESULT "):])

@@ -71,7 +71,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams, default_interpret
+from repro.kernels.pallas_compat import default_interpret
 
 __all__ = [
     "decode_flash_attention_pallas",
@@ -136,7 +136,7 @@ def _kernel(len_ref, qlen_ref, *refs, scale, window, bk, max_len, rep, chunk,
         if quant:
             # scale-after-dot: the int8 dot is integer-exact in bf16; the
             # per-token fp scale multiplies the finished partial sum
-            s = s * ks_ref[0, 0][None, :]
+            s = s * ks_ref[0, 0]                           # (1, bk)
         s = s * scale
 
         rows = rep * chunk
@@ -159,7 +159,7 @@ def _kernel(len_ref, qlen_ref, *refs, scale, window, bk, max_len, rep, chunk,
 
         if quant:
             # fold the per-token v scale into the probabilities (linear in v)
-            p = p * vs_ref[0, 0][None, :]
+            p = p * vs_ref[0, 0]
         pv = jax.lax.dot_general(
             p.astype(q.dtype), v_ref[0, 0].astype(q.dtype),
             dimension_numbers=(((1,), (0,)), ((), ())),
@@ -257,7 +257,8 @@ def mixed_flash_attention_pallas(
         return (ib, h, lg, 0)
 
     def kv_scale_map(ib, h, ik, len_ref, qlen_ref, *pt_ref):
-        return kv_map(ib, h, ik, len_ref, qlen_ref, *pt_ref)[:3]
+        blk, h, lg, _ = kv_map(ib, h, ik, len_ref, qlen_ref, *pt_ref)
+        return (blk, h, 0, lg)
 
     def q_map(ib, h, ik, len_ref, qlen_ref, *pt_ref):
         return (ib, h, 0, 0)
@@ -269,12 +270,14 @@ def mixed_flash_attention_pallas(
     ]
     operands = [q4, k_cache, v_cache]
     if quant:
+        # scales ride as (.., 1, tokens): a block's last two dims must tile
+        # (8k, 128m) or span the array, and a lone token row does neither
         in_specs += [
-            pl.BlockSpec((1, 1, bk), kv_scale_map),
-            pl.BlockSpec((1, 1, bk), kv_scale_map),
+            pl.BlockSpec((1, 1, 1, bk), kv_scale_map),
+            pl.BlockSpec((1, 1, 1, bk), kv_scale_map),
         ]
-        scale_shape = ((k_cache.shape[0], hkv, bk) if paged
-                       else (b, hkv, max_len))
+        scale_shape = ((k_cache.shape[0], hkv, 1, bk) if paged
+                       else (b, hkv, 1, max_len))
         operands += [
             k_scale.astype(jnp.float32).reshape(scale_shape),
             v_scale.astype(jnp.float32).reshape(scale_shape),
@@ -302,9 +305,10 @@ def mixed_flash_attention_pallas(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="mixed_flash_attention",
         interpret=interpret,
     )(*prefetch, *operands)
     return out.reshape(b, hkv, rep, chunk, d).reshape(b, hq, chunk, d)

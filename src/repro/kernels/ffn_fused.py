@@ -60,7 +60,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.quant import GROUP_SIZE, QuantizedTensor
 from repro.core.sparsity import SparseQuantizedTensor
 from repro.kernels.pallas_compat import (
-    CompilerParams, default_interpret, token_block)
+    default_interpret, pick_row, token_block, unpack_int4_rows)
 
 __all__ = [
     "DEFAULT_BLOCK_TOKENS",
@@ -76,19 +76,6 @@ DEFAULT_BLOCK_TOKENS = 128
 
 GATED_ACTIVATIONS = ("swiglu", "geglu")
 ACTIVATIONS = GATED_ACTIVATIONS + ("gelu",)
-
-
-def _unpack_rows(packed_u8: jax.Array, dtype=jnp.bfloat16) -> jax.Array:
-    """(..., 64, n) packed nibbles -> (..., 128, n) int4 values as ``dtype``.
-
-    Sublane-pair packing (core.quant): one mask, one shift, one sublane
-    concat — integer-exact in bf16 and f32 alike.  The single unpack used
-    by every path in this module (in-kernel blocks and the XLA twin)."""
-    lo = (packed_u8 & 0xF).astype(jnp.int8)
-    hi = (packed_u8 >> 4).astype(jnp.int8)
-    lo = jnp.where(lo >= 8, lo - 16, lo)
-    hi = jnp.where(hi >= 8, hi - 16, hi)
-    return jnp.concatenate([lo, hi], axis=-2).astype(dtype)
 
 
 def _apply_act(name: str, gate_f32, u_f32):
@@ -112,13 +99,22 @@ def _dot_f32(a: jax.Array, b: jax.Array) -> jax.Array:
 # dense-layout kernels (fp16 weights / dense-quantized W4A16)
 # ---------------------------------------------------------------------------
 
-def _make_kernel(activation: str, gated: bool, bias: bool, quant: bool):
+def _scale_rows(n_groups: int) -> int:
+    """Rows of a down-projection scale block: one 8-row sublane tile of
+    groups, or the whole group axis when it is shorter."""
+    return min(8, n_groups)
+
+
+def _make_kernel(activation: str, gated: bool, bias: bool, quant: bool,
+                 rows: int = 1):
     """Kernel body for the dense-layout fused FFN.
 
     Grid (token_blocks, f_tiles, d_groups); operand order (quant):
       x, [gate packed+scales], up packed+scales, down packed+scales,
       [up_bias, down_bias], out, scratch: [gate_acc], up_acc, out_acc.
     fp variant drops the packed/scales pairs for plain (128, ·) blocks.
+    Gate/up scale blocks hold all d-groups of an f-tile; down scale blocks
+    hold ``rows`` f-tiles' groups.
     """
 
     def kernel(*refs):
@@ -153,9 +149,11 @@ def _make_kernel(activation: str, gated: bool, bias: bool, quant: bool):
 
         def proj(refs_):
             if quant:
+                # scale blocks hold every group (Mosaic tiles a block's last
+                # two dims); the contraction group g is one row of it
                 pk, sc = refs_
-                w = _unpack_rows(pk[...])                       # (128, 128)
-                return _dot_f32(xg, w) * sc[...].astype(jnp.float32)
+                w = unpack_int4_rows(pk[...])                  # (128, 128)
+                return _dot_f32(xg, w) * pick_row(sc[...], g)
             (w_ref,) = refs_
             return _dot_f32(xg, w_ref[...].astype(x_ref.dtype))
 
@@ -172,8 +170,8 @@ def _make_kernel(activation: str, gated: bool, bias: bool, quant: bool):
             h16 = h.astype(x_ref.dtype)
             if quant:
                 pk, sc = d_refs
-                wd = _unpack_rows(pk[...])                      # (128, out_f)
-                part = _dot_f32(h16, wd) * sc[...].astype(jnp.float32)
+                wd = unpack_int4_rows(pk[...])                 # (128, out_f)
+                part = _dot_f32(h16, wd) * pick_row(sc[...], j % rows)
             else:
                 (wd_ref,) = d_refs
                 part = _dot_f32(h16, wd_ref[...].astype(x_ref.dtype))
@@ -251,20 +249,21 @@ def ffn_fused_w4a16_pallas(
     nj, ng = f // GROUP_SIZE, in_f // GROUP_SIZE
     grid = (x2.shape[0] // bt, nj, ng)
     bias = not gated
+    rows = _scale_rows(nj)
 
     in_specs = [pl.BlockSpec((bt, in_f), lambda t, j, g: (t, 0))]
     args = [x2]
     if gated:
         in_specs += [
             pl.BlockSpec((_HALF, GROUP_SIZE), lambda t, j, g: (g, j)),
-            pl.BlockSpec((1, GROUP_SIZE), lambda t, j, g: (g, j)),
+            pl.BlockSpec((ng, GROUP_SIZE), lambda t, j, g: (0, j)),
         ]
         args += [gate.packed, gate.scales]
     in_specs += [
         pl.BlockSpec((_HALF, GROUP_SIZE), lambda t, j, g: (g, j)),
-        pl.BlockSpec((1, GROUP_SIZE), lambda t, j, g: (g, j)),
+        pl.BlockSpec((ng, GROUP_SIZE), lambda t, j, g: (0, j)),
         pl.BlockSpec((_HALF, out_f), lambda t, j, g: (j, 0)),
-        pl.BlockSpec((1, out_f), lambda t, j, g: (j, 0)),
+        pl.BlockSpec((rows, out_f), lambda t, j, g: (j // rows, 0)),
     ]
     args += [up.packed, up.scales, down.packed, down.scales]
     if bias:
@@ -280,14 +279,15 @@ def ffn_fused_w4a16_pallas(
         pltpu.VMEM((bt, out_f), jnp.float32),
     ]
     out = pl.pallas_call(
-        _make_kernel(activation, gated, bias, quant=True),
+        _make_kernel(activation, gated, bias, quant=True, rows=rows),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bt, out_f), lambda t, j, g: (t, 0)),
         out_shape=jax.ShapeDtypeStruct((x2.shape[0], out_f), x.dtype),
         scratch_shapes=scratch,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        name="ffn_fused_w4a16",
         interpret=interpret,
     )(*args)
     if n_tok != x2.shape[0]:
@@ -357,8 +357,9 @@ def ffn_fused_dense_pallas(
         out_specs=pl.BlockSpec((bt, out_f), lambda t, j, g: (t, 0)),
         out_shape=jax.ShapeDtypeStruct((x2.shape[0], out_f), x.dtype),
         scratch_shapes=scratch,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        name="ffn_fused_dense",
         interpret=interpret,
     )(*args)
     if n_tok != x2.shape[0]:
@@ -371,7 +372,7 @@ def ffn_fused_dense_pallas(
 # ---------------------------------------------------------------------------
 
 def _make_sparse_kernel(activation: str, gated: bool, bias: bool,
-                        down_sparse: bool):
+                        down_sparse: bool, rows: int = 1):
     """Kernel body for the sparse fused FFN.
 
     Grid (token_blocks, down_f_steps, kept_contraction_blocks).  Prefetch
@@ -413,13 +414,15 @@ def _make_sparse_kernel(activation: str, gated: bool, bias: bool,
 
         # activation gather: the kept d-block index picks the slice of the
         # RESIDENT x block (sparse_w4a16's DMA-side gather, moved on-chip)
+        # scale blocks hold all kept blocks of the tile (Mosaic tiles a
+        # block's last two dims); kept block sg is one row of it
         xu = x_ref[:, pl.ds(ui_ref[jf, sg] * GROUP_SIZE, GROUP_SIZE)]
-        wu = _unpack_rows(upk_ref[0, 0])                       # (128, 128)
-        uacc[...] += _dot_f32(xu, wu) * usc_ref[0].astype(jnp.float32)
+        wu = unpack_int4_rows(upk_ref[0, 0])                  # (128, 128)
+        uacc[...] += _dot_f32(xu, wu) * pick_row(usc_ref[0], sg)
         if gated:
             xg = x_ref[:, pl.ds(gi_ref[jf, sg] * GROUP_SIZE, GROUP_SIZE)]
-            wg = _unpack_rows(gpk_ref[0, 0])
-            gacc[...] += _dot_f32(xg, wg) * gsc_ref[0].astype(jnp.float32)
+            wg = unpack_int4_rows(gpk_ref[0, 0])
+            gacc[...] += _dot_f32(xg, wg) * pick_row(gsc_ref[0], sg)
 
         @pl.when(sg == nsg - 1)
         def _tile_done():
@@ -429,15 +432,15 @@ def _make_sparse_kernel(activation: str, gated: bool, bias: bool,
             h = _apply_act(activation, gacc[...] if gated else None, u)
             h16 = h.astype(x_ref.dtype)
             if down_sparse:
-                wd = _unpack_rows(dpk_ref[:, 0])               # (Td, 128, 128)
+                wd = unpack_int4_rows(dpk_ref[:, 0])          # (Td, 128, 128)
                 part = jax.lax.dot_general(
                     h16, wd, dimension_numbers=(((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)        # (bt, Td, 128)
-                part = part * dsc_ref[:, 0].astype(jnp.float32)[None]
+                part = part * pick_row(dsc_ref[...], s, axis=1)[:, 0][None]
                 oacc[...] += part.reshape(part.shape[0], -1)
             else:
-                wd = _unpack_rows(dpk_ref[...])                # (128, out_f)
-                part = _dot_f32(h16, wd) * dsc_ref[...].astype(jnp.float32)
+                wd = unpack_int4_rows(dpk_ref[...])           # (128, out_f)
+                part = _dot_f32(h16, wd) * pick_row(dsc_ref[...], jf % rows)
                 oacc[...] += part
 
         @pl.when((sg == nsg - 1) & (s == ns - 1))
@@ -502,6 +505,7 @@ def ffn_fused_sparse_pallas(
         n_fsteps = n_ftiles
     grid = (nt, n_fsteps, sc)
     bias = not gated
+    rows = _scale_rows(n_ftiles)
 
     # prefetch + tensor operands; index maps receive the prefetch refs last
     prefetch = [ftile]
@@ -519,15 +523,15 @@ def ffn_fused_sparse_pallas(
         in_specs += [
             pl.BlockSpec((1, 1, _HALF, GROUP_SIZE),
                          lambda t, s, sg, *r: (_ft(s, r), sg, 0, 0)),
-            pl.BlockSpec((1, 1, GROUP_SIZE),
-                         lambda t, s, sg, *r: (_ft(s, r), sg, 0)),
+            pl.BlockSpec((1, sc, GROUP_SIZE),
+                         lambda t, s, sg, *r: (_ft(s, r), 0, 0)),
         ]
         args += [gate.packed, gate.scales]
     in_specs += [
         pl.BlockSpec((1, 1, _HALF, GROUP_SIZE),
                      lambda t, s, sg, *r: (_ft(s, r), sg, 0, 0)),
-        pl.BlockSpec((1, 1, GROUP_SIZE),
-                     lambda t, s, sg, *r: (_ft(s, r), sg, 0)),
+        pl.BlockSpec((1, sc, GROUP_SIZE),
+                     lambda t, s, sg, *r: (_ft(s, r), 0, 0)),
     ]
     args += [up.packed, up.scales]
     if down_sparse:
@@ -535,15 +539,15 @@ def ffn_fused_sparse_pallas(
         in_specs += [
             pl.BlockSpec((td, 1, _HALF, GROUP_SIZE),
                          lambda t, s, sg, *r: (0, s, 0, 0)),
-            pl.BlockSpec((td, 1, GROUP_SIZE),
-                         lambda t, s, sg, *r: (0, s, 0)),
+            pl.BlockSpec((td, n_fsteps, GROUP_SIZE),
+                         lambda t, s, sg, *r: (0, 0, 0)),
         ]
     else:
         in_specs += [
             pl.BlockSpec((_HALF, out_f),
                          lambda t, s, sg, *r: (_ft(s, r), 0)),
-            pl.BlockSpec((1, out_f),
-                         lambda t, s, sg, *r: (_ft(s, r), 0)),
+            pl.BlockSpec((rows, out_f),
+                         lambda t, s, sg, *r: (_ft(s, r) // rows, 0)),
         ]
     args += [down.packed, down.scales]
     if bias:
@@ -560,7 +564,7 @@ def ffn_fused_sparse_pallas(
         pltpu.VMEM((bt, out_f), jnp.float32),
     ]
     out = pl.pallas_call(
-        _make_sparse_kernel(activation, gated, bias, down_sparse),
+        _make_sparse_kernel(activation, gated, bias, down_sparse, rows),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=n_pre,
             grid=grid,
@@ -569,8 +573,9 @@ def ffn_fused_sparse_pallas(
             scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((x2.shape[0], out_f), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        name="ffn_fused_sparse",
         interpret=interpret,
     )(*(prefetch + args))
     if n_tok != x2.shape[0]:
@@ -591,7 +596,7 @@ def _unpack_f32(packed: jax.Array, group_size: int) -> jax.Array:
     round trip through memory, the twin's decode-shape win)."""
     half = group_size // 2
     out_f = packed.shape[-1]
-    return _unpack_rows(packed.reshape(-1, half, out_f), jnp.float32)
+    return unpack_int4_rows(packed.reshape(-1, half, out_f), jnp.float32)
 
 
 def w4a16_matmul_f32(x: jax.Array, qt: QuantizedTensor) -> jax.Array:
@@ -611,7 +616,7 @@ def sparse_matmul_f32(x: jax.Array, st: SparseQuantizedTensor) -> jax.Array:
     g = st.group_size
     *lead, tokens, _ = x.shape
     xb = x.reshape(-1, in_f // g, g).astype(jnp.float32)
-    w = _unpack_rows(st.packed, jnp.float32)                   # (T,S,128,128)
+    w = unpack_int4_rows(st.packed, jnp.float32)              # (T,S,128,128)
     xg = jnp.take(xb, st.block_idx, axis=1)                    # (N,T,S,128)
     part = jnp.einsum("ntsg,tsgo->ntso", xg, w,
                       preferred_element_type=jnp.float32)

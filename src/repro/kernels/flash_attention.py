@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams, default_interpret
+from repro.kernels.pallas_compat import default_interpret
 
 __all__ = ["flash_attention_pallas"]
 
@@ -155,9 +155,10 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, _STATS), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="flash_attention",
         interpret=interpret,
     )(q3, k3, v3)
     return out.reshape(b, hq, sq, d)

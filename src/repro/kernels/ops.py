@@ -7,13 +7,17 @@ Each op dispatches between:
 * ``impl="xla"``      — a pure-XLA implementation with the *same numerics
   contract* (group-exact scale-after-dot).  This is what the 512-device
   dry-run lowers (Pallas cannot target the CPU dry-run backend), and the
-  fallback for shapes the kernels don't tile.
+  fallback for shapes the kernels don't tile.  A fallback never happens in
+  silence: it raises a :class:`KernelFallbackWarning`, which a chip run can
+  turn into an error.
 
 The op-graph compiler (``core/compiler.py``) selects the impl per operator;
 models only ever call these wrappers.
 """
 
 from __future__ import annotations
+
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -27,17 +31,31 @@ from repro.kernels.sparse_w4a16 import sparse_w4a16_matmul_pallas
 from repro.kernels.w4a16_matmul import w4a16_matmul_pallas
 
 __all__ = ["w4a16_matmul", "sparse_w4a16_matmul", "ffn_w4a16", "attention",
-           "decode_attention", "mixed_attention", "gather_paged_cache"]
+           "decode_attention", "mixed_attention", "gather_paged_cache",
+           "KernelFallbackWarning"]
 
-# one backend probe for the whole package: the kernels resolve their
-# interpret=None default through the same (cached) function
-_ON_TPU = not default_interpret()
+
+class KernelFallbackWarning(RuntimeWarning):
+    """A selected Pallas kernel could not take its operands, and the XLA
+    twin ran in its place."""
+
+
+def _fallback(op: str, why: str) -> None:
+    warnings.warn(f"{op}: the Pallas kernel gave way to the XLA twin ({why})",
+                  KernelFallbackWarning, stacklevel=3)
+
+
+def _auto() -> str:
+    # probed at the first call, never at import (the probe takes the
+    # device); the kernels resolve interpret=None through the same cached
+    # function
+    return "xla" if default_interpret() else "pallas"
 
 
 def w4a16_matmul(x: jax.Array, qt: QuantizedTensor, *, impl: str = "auto") -> jax.Array:
     """x @ dequant(qt); group-exact W4A16 numerics on every path."""
     if impl == "auto":
-        impl = "pallas" if _ON_TPU else "xla"
+        impl = _auto()
     if impl == "pallas":
         return w4a16_matmul_pallas(x, qt)
     if impl == "xla":
@@ -49,7 +67,7 @@ def sparse_w4a16_matmul(
     x: jax.Array, st: SparseQuantizedTensor, *, impl: str = "auto"
 ) -> jax.Array:
     if impl == "auto":
-        impl = "pallas" if _ON_TPU else "xla"
+        impl = _auto()
     if impl == "pallas":
         return sparse_w4a16_matmul_pallas(x, st)
     if impl == "xla":
@@ -89,7 +107,7 @@ def ffn_w4a16(
     * ``impl="ref"``    — the unfused 3-matmul oracle.
     """
     if impl == "auto":
-        impl = "pallas" if _ON_TPU else "xla"
+        impl = _auto()
     gated = activation in ("swiglu", "geglu")
     if gated and (up_bias is not None or down_bias is not None):
         raise ValueError("gated activations take no FFN biases")
@@ -115,6 +133,7 @@ def ffn_w4a16(
             return ffn_fused.ffn_fused_dense_pallas(
                 x, gate if gated else None, up, down, activation=activation,
                 up_bias=up_bias, down_bias=down_bias)
+        _fallback("ffn_w4a16", "no fused kernel tiles these operands")
         impl = "xla"
     if impl == "xla":
         if not quantized:
@@ -138,7 +157,7 @@ def attention(
 ) -> jax.Array:
     """Fused attention (MODE-0). q (b,hq,sq,d), k/v (b,hkv,skv,d)."""
     if impl == "auto":
-        impl = "pallas" if _ON_TPU else "xla"
+        impl = _auto()
     if impl == "pallas":
         return flash_attention_pallas(
             q, k, v, causal=causal, window=window, scale=scale)
@@ -221,7 +240,7 @@ def decode_attention(
       ``benchmarks/decode_bench.py`` measures against.
     """
     if impl == "auto":
-        impl = "pallas" if _ON_TPU else "xla"
+        impl = _auto()
     if impl == "pallas":
         from repro.kernels.decode_flash import (
             DEFAULT_BLOCK_KV, decode_flash_attention_pallas, kv_block_size)
@@ -231,7 +250,8 @@ def decode_attention(
             return decode_flash_attention_pallas(
                 q, k_cache, v_cache, length, window=window, scale=scale,
                 k_scale=k_scale, v_scale=v_scale, page_table=page_table)
-        impl = "xla"  # cache length tiles too poorly for the kernel
+        _fallback("decode_attention", "cache length tiles too poorly")
+        impl = "xla"
     if impl == "xla":
         from repro.kernels.xla_attention import decode_attention_blocked
         return decode_attention_blocked(
@@ -275,7 +295,7 @@ def mixed_attention(
     * ``impl="ref"``    — the dense full-cache oracle.
     """
     if impl == "auto":
-        impl = "pallas" if _ON_TPU else "xla"
+        impl = _auto()
     if impl == "pallas":
         from repro.kernels.decode_flash import (
             DEFAULT_BLOCK_KV, kv_block_size, mixed_flash_attention_pallas)
@@ -286,7 +306,8 @@ def mixed_attention(
                 q, k_cache, v_cache, lengths, q_lens, window=window,
                 scale=scale, k_scale=k_scale, v_scale=v_scale,
                 page_table=page_table)
-        impl = "xla"  # cache length tiles too poorly for the kernel
+        _fallback("mixed_attention", "cache length tiles too poorly")
+        impl = "xla"
     if impl == "xla":
         from repro.kernels.xla_attention import mixed_attention_blocked
         return mixed_attention_blocked(

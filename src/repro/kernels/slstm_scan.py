@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 
 __all__ = ["slstm_scan_pallas"]
 
@@ -104,9 +103,10 @@ def slstm_scan_pallas(
         out_shape=jax.ShapeDtypeStruct((b, L, heads, dh), gates_x.dtype),
         scratch_shapes=[pltpu.VMEM((heads, dh), jnp.float32)] * 3
         + [pltpu.VMEM((heads, dh), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
+        name="slstm_scan",
         interpret=interpret,
     )(gates_x, r_gates, b_gates)
     return out

@@ -32,7 +32,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.pallas_compat import (
-    CompilerParams, default_interpret, token_block)
+    default_interpret, pick_row, token_block, unpack_int4_rows)
 
 from repro.core.quant import GROUP_SIZE
 from repro.core.sparsity import SparseQuantizedTensor
@@ -42,14 +42,6 @@ __all__ = ["sparse_w4a16_matmul_pallas"]
 _HALF = GROUP_SIZE // 2
 
 
-def _unpack_block(packed_u8: jax.Array) -> jax.Array:
-    lo = (packed_u8 & 0xF).astype(jnp.int8)
-    hi = (packed_u8 >> 4).astype(jnp.int8)
-    lo = jnp.where(lo >= 8, lo - 16, lo)
-    hi = jnp.where(hi >= 8, hi - 16, hi)
-    return jnp.concatenate([lo, hi], axis=0).astype(jnp.bfloat16)
-
-
 def _kernel(idx_ref, x_ref, packed_ref, scale_ref, o_ref, acc_ref):
     s = pl.program_id(2)
 
@@ -57,13 +49,15 @@ def _kernel(idx_ref, x_ref, packed_ref, scale_ref, o_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = _unpack_block(packed_ref[0, 0])                    # (128, 128) bf16
+    w = unpack_int4_rows(packed_ref[0, 0])               # (128, 128) bf16
     part = jax.lax.dot_general(
         x_ref[...], w,
         dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    acc_ref[...] += part * scale_ref[0].astype(jnp.float32)
+    # the scale block holds all S kept blocks of this out tile (Mosaic tiles
+    # a block's last two dims); this step's block is one row of it
+    acc_ref[...] += part * pick_row(scale_ref[0], s)
 
     @pl.when(s == pl.num_programs(2) - 1)
     def _done():
@@ -114,17 +108,18 @@ def sparse_w4a16_matmul_pallas(
                     (1, 1, _HALF, GROUP_SIZE),
                     lambda t, o, s, idx_ref: (o, s, 0, 0)),
                 pl.BlockSpec(
-                    (1, 1, GROUP_SIZE),
-                    lambda t, o, s, idx_ref: (o, s, 0)),
+                    (1, S, GROUP_SIZE),
+                    lambda t, o, s, idx_ref: (o, 0, 0)),
             ],
             out_specs=pl.BlockSpec(
                 (bt, GROUP_SIZE), lambda t, o, s, idx_ref: (t, o)),
             scratch_shapes=[pltpu.VMEM((bt, GROUP_SIZE), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((x2.shape[0], out_f), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="sparse_w4a16_matmul",
         interpret=interpret,
     )(st.block_idx, x2, st.packed, st.scales)
     if pad:

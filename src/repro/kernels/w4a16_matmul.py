@@ -20,8 +20,9 @@ Roofline intent (paper Fig. 3): at decode (bt small) the kernel moves
 ``2·bt·in·out`` FLOPs — arithmetic intensity ≈ bt·4 FLOP/byte, memory-bound
 until bt ≈ 100, exactly the regime the paper sizes its PE bandwidth for.
 
-VMEM budget per step: x (bt·128·2) + packed (64·bo) + scales (2·bo) + acc
-(bt·bo·4) bytes; defaults (bt=256, bo=512) ≈ 1.1 MB « 16 MB v5e VMEM,
+VMEM budget per step: x (bt·128·2) + packed (64·bo) + scales
+(2·bo·in/128) + acc (bt·bo·4) bytes; defaults (bt=256, bo=512, in=4096)
+≈ 1.1 MB « 16 MB v5e VMEM,
 leaving room for Mosaic's double buffering of the streamed weight blocks.
 """
 
@@ -35,22 +36,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.pallas_compat import (
-    CompilerParams, default_interpret, token_block)
+    default_interpret, pick_row, token_block, unpack_int4_rows)
 
 from repro.core.quant import GROUP_SIZE, QuantizedTensor
 
 __all__ = ["w4a16_matmul_pallas"]
 
 _HALF = GROUP_SIZE // 2  # 64 packed rows per 128-row group
-
-
-def _unpack_group(packed_u8: jax.Array) -> jax.Array:
-    """(64, bo) uint8 nibbles -> (128, bo) int4 values as bf16 (exact)."""
-    lo = (packed_u8 & 0xF).astype(jnp.int8)
-    hi = (packed_u8 >> 4).astype(jnp.int8)
-    lo = jnp.where(lo >= 8, lo - 16, lo)
-    hi = jnp.where(hi >= 8, hi - 16, hi)
-    return jnp.concatenate([lo, hi], axis=0).astype(jnp.bfloat16)
 
 
 def _kernel(x_ref, packed_ref, scale_ref, o_ref, acc_ref):
@@ -60,13 +52,15 @@ def _kernel(x_ref, packed_ref, scale_ref, o_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = _unpack_group(packed_ref[...])                     # (128, bo) bf16, integer-exact
+    w = unpack_int4_rows(packed_ref[...])                  # (128, bo) bf16, integer-exact
     part = jax.lax.dot_general(
         x_ref[...], w,
         dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                                      # (bt, bo) f32
-    acc_ref[...] += part * scale_ref[...].astype(jnp.float32)  # (1, bo) scale bcast
+    # the scale block holds every group of this out tile (Mosaic tiles a
+    # block's last two dims); this step's group is one row of it
+    acc_ref[...] += part * pick_row(scale_ref[...], g)     # (1, bo)
 
     @pl.when(g == pl.num_programs(2) - 1)
     def _done():
@@ -105,26 +99,27 @@ def w4a16_matmul_pallas(
     pad = (-n_tok) % bt
     if pad:
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
+    # a ragged last out tile (e.g. a 151936-wide lm_head) is a partial
+    # block: its out-of-range columns are computed on padding and dropped
     bo = min(block_out, out_f)
-    if out_f % bo:
-        raise ValueError(f"out_features {out_f} not a multiple of block_out {bo}")
     n_groups = in_f // GROUP_SIZE
 
-    grid = (x2.shape[0] // bt, out_f // bo, n_groups)
+    grid = (x2.shape[0] // bt, pl.cdiv(out_f, bo), n_groups)
     out = pl.pallas_call(
         _kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bt, GROUP_SIZE), lambda t, o, g: (t, g)),
             pl.BlockSpec((_HALF, bo), lambda t, o, g: (g, o)),
-            pl.BlockSpec((1, bo), lambda t, o, g: (g, o)),
+            pl.BlockSpec((n_groups, bo), lambda t, o, g: (0, o)),
         ],
         out_specs=pl.BlockSpec((bt, bo), lambda t, o, g: (t, o)),
         out_shape=jax.ShapeDtypeStruct((x2.shape[0], out_f), x.dtype),
         scratch_shapes=[pltpu.VMEM((bt, bo), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="w4a16_matmul",
         interpret=interpret,
     )(x2, qt.packed, qt.scales)
     if pad:

@@ -15,7 +15,8 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
-from repro.core.compiler import quantize_model, quantized_bytes
+from repro.core.compiler import init_quantized, quantized_bytes
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.serving.engine import Engine, Request
 
@@ -99,13 +100,17 @@ def main() -> None:
     if args.restore and not args.snapshot_dir:
         ap.error("--restore requires --snapshot-dir")
 
+    enable_compile_cache()
     kv = dict(kv_layout=args.kv_layout, kv_block_size=args.kv_block_size,
               kv_pool_blocks=args.kv_pool_blocks)
     cfg = (get_config(args.arch, **kv) if args.full
            else get_smoke_config(args.arch, **kv))
-    params = api.init_params(cfg, jax.random.PRNGKey(0))
-    if args.strategy != "none":
-        params = quantize_model(params, args.strategy)
+    key = jax.random.PRNGKey(0)
+    if args.strategy == "none":
+        params = api.init_params(cfg, key)
+    else:
+        params = init_quantized(lambda k: api.init_params(cfg, k), key,
+                                args.strategy)
     print(f"arch={cfg.name} packed={quantized_bytes(params)/1e6:.1f} MB "
           f"strategy={args.strategy}")
 

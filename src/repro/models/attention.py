@@ -15,6 +15,12 @@ from repro.models import layers
 from repro.models.layers import Params, dense_init, linear
 
 
+def _impl(cfg) -> str:
+    """The ``ops`` implementation every attention call of this model uses:
+    the Pallas kernels under ``use_kernels``, the XLA twins otherwise."""
+    return "pallas" if cfg.use_kernels else "xla"
+
+
 def attn_init(key, cfg, d_model: int | None = None) -> Params:
     d = d_model or cfg.d_model
     hd, hq, hkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
@@ -63,7 +69,7 @@ def attn_apply(cfg, p: Params, x: jax.Array, positions, *,
     b, s, _ = x.shape
     q, k, v = _project_qkv(cfg, p, x, positions)
     o = ops.attention(q, k, v, causal=causal, window=cfg.window,
-                      impl="pallas" if cfg.use_kernels else "xla")
+                      impl=_impl(cfg))
     o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * cfg.head_dim)
     return linear(o, p["wo"], use_kernels=cfg.use_kernels)
 
@@ -238,7 +244,7 @@ def attn_prefill(cfg, p: Params, x: jax.Array, positions, cache: Params):
     b, s, _ = x.shape
     q, k, v = _project_qkv(cfg, p, x, positions)
     o = ops.attention(q, k, v, causal=True, window=cfg.window,
-                      impl="pallas" if cfg.use_kernels else "xla")
+                      impl=_impl(cfg))
     o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * cfg.head_dim)
     out = linear(o, p["wo"], use_kernels=cfg.use_kernels)
     cache_len = cache["k"].shape[2]
@@ -338,7 +344,8 @@ def attn_mixed(cfg, p: Params, x: jax.Array, positions, cache: Params,
                                     q_lens, window=cfg.window,
                                     k_scale=new_cache["k_scale"],
                                     v_scale=new_cache["v_scale"],
-                                    page_table=page_table)
+                                    page_table=page_table,
+                                    impl=_impl(cfg))
         else:
             new_cache = {
                 "k": _paged_chunk_write(cache["k"], k, page_table,
@@ -348,7 +355,8 @@ def attn_mixed(cfg, p: Params, x: jax.Array, positions, cache: Params,
             }
             o = ops.mixed_attention(q, new_cache["k"], new_cache["v"], total,
                                     q_lens, window=cfg.window,
-                                    page_table=page_table)
+                                    page_table=page_table,
+                                    impl=_impl(cfg))
         o = o.transpose(0, 2, 1, 3).reshape(b, c, cfg.n_heads * cfg.head_dim)
         return linear(o, p["wo"], use_kernels=cfg.use_kernels), new_cache
 
@@ -364,14 +372,16 @@ def attn_mixed(cfg, p: Params, x: jax.Array, positions, cache: Params,
         o = ops.mixed_attention(q, new_cache["k"], new_cache["v"], total,
                                 q_lens, window=cfg.window,
                                 k_scale=new_cache["k_scale"],
-                                v_scale=new_cache["v_scale"])
+                                v_scale=new_cache["v_scale"],
+                                impl=_impl(cfg))
     else:
         new_cache = {
             "k": _chunk_write(cache["k"], k, lengths, q_lens),
             "v": _chunk_write(cache["v"], v, lengths, q_lens),
         }
         o = ops.mixed_attention(q, new_cache["k"], new_cache["v"], total,
-                                q_lens, window=cfg.window)
+                                q_lens, window=cfg.window,
+                                impl=_impl(cfg))
     o = o.transpose(0, 2, 1, 3).reshape(b, c, cfg.n_heads * cfg.head_dim)
     out = linear(o, p["wo"], use_kernels=cfg.use_kernels)
     return out, new_cache
@@ -464,7 +474,8 @@ def attn_decode(cfg, p: Params, x: jax.Array, positions, cache: Params,
         o = ops.decode_attention(q, new_cache["k"], new_cache["v"], attn_len,
                                  window=attn_window,
                                  k_scale=new_cache["k_scale"],
-                                 v_scale=new_cache["v_scale"])
+                                 v_scale=new_cache["v_scale"],
+                                 impl=_impl(cfg))
         o = o.transpose(0, 2, 1, 3).reshape(b, 1, cfg.n_heads * cfg.head_dim)
         out = linear(o, p["wo"], use_kernels=cfg.use_kernels)
         return out, new_cache
@@ -480,7 +491,8 @@ def attn_decode(cfg, p: Params, x: jax.Array, positions, cache: Params,
             return jax.lax.dynamic_update_slice(c, new, (0, l, 0))
         k_new = jax.vmap(upd)(cache["k"], k.astype(cache["k"].dtype), write_idx)
         v_new = jax.vmap(upd)(cache["v"], v.astype(cache["v"].dtype), write_idx)
-    o = ops.decode_attention(q, k_new, v_new, attn_len, window=attn_window)
+    o = ops.decode_attention(q, k_new, v_new, attn_len, window=attn_window,
+                             impl=_impl(cfg))
     o = o.transpose(0, 2, 1, 3).reshape(b, 1, cfg.n_heads * cfg.head_dim)
     out = linear(o, p["wo"], use_kernels=cfg.use_kernels)
     return out, {"k": k_new, "v": v_new}
@@ -544,7 +556,8 @@ def _attn_decode_paged(cfg, p: Params, q, k, v, cache: Params, lengths,
                                  window=attn_window,
                                  k_scale=new_cache["k_scale"],
                                  v_scale=new_cache["v_scale"],
-                                 page_table=page_table)
+                                 page_table=page_table,
+                                 impl=_impl(cfg))
     else:
         new_cache = {
             "k": _paged_token_write(cache["k"], k[:, :, 0], page_table,
@@ -553,7 +566,8 @@ def _attn_decode_paged(cfg, p: Params, q, k, v, cache: Params, lengths,
                                     write_idx, write_mask),
         }
         o = ops.decode_attention(q, new_cache["k"], new_cache["v"], attn_len,
-                                 window=attn_window, page_table=page_table)
+                                 window=attn_window, page_table=page_table,
+                                 impl=_impl(cfg))
     o = o.transpose(0, 2, 1, 3).reshape(b, 1, cfg.n_heads * cfg.head_dim)
     return linear(o, p["wo"], use_kernels=cfg.use_kernels), new_cache
 
@@ -572,7 +586,7 @@ def cross_attn_apply(cfg, p: Params, x: jax.Array, enc_kv: tuple) -> jax.Array:
     q = q.reshape(b, s, hq, hd).transpose(0, 2, 1, 3)
     k, v = enc_kv
     o = ops.attention(q, k, v, causal=False,
-                      impl="pallas" if cfg.use_kernels else "xla")
+                      impl=_impl(cfg))
     o = o.transpose(0, 2, 1, 3).reshape(b, s, hq * hd)
     return linear(o, p["wo"], use_kernels=cfg.use_kernels)
 

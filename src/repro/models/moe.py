@@ -36,7 +36,6 @@ from repro.core.quant import QuantizedTensor
 from repro.core.sparsity import SparseQuantizedTensor
 from repro.kernels import ops
 from repro.models.layers import Params, dense_init, linear
-from repro.parallel.compat import shard_map
 from repro.parallel.hints import active_mesh
 
 
@@ -200,7 +199,7 @@ def _moe_apply_shard_map_quant(cfg, p: Params, x: jax.Array, mesh):
     col_sc = P(None, None, "model")       # (E, d/gs, f)
     row_pk = P(None, "model", None)       # (E, f/2, d)
     row_sc = P(None, "model", None)       # (E, f/gs, d)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(da, None, None), P(), col_pk, col_sc, col_pk, col_sc,
                   row_pk, row_sc),
@@ -248,7 +247,7 @@ def _moe_apply_shard_map(cfg, p: Params, x: jax.Array, mesh):
         out = jax.lax.psum(out, "model")                      # Megatron row sum
         return out, aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(da, None, None), P(), P(None, None, wspec),
                   P(None, None, wspec), P(None, wspec, None)),

@@ -39,8 +39,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.compat import shard_map
-
 
 def seq_axes_for(mesh: Mesh, batch: int) -> tuple[str, ...]:
     """Mirror kv_cache_specs: seq shards over 'model', plus the data axes
@@ -150,19 +148,19 @@ def decode_attention_sharded(
     cache_spec = P(batch_ax, None, sa if len(sa) > 1 else sa[0], None)
     rep_spec = P(batch_ax, None, None, None)
     len_spec = P(batch_ax)          # per-row lengths ride with the batch
-    # check_rep=False: the blocked partials walk is a lax.while_loop (trip
+    # check_vma=False: the blocked partials walk is a lax.while_loop (trip
     # count = this shard's live blocks), which shard_map's replication
     # checker cannot type yet; the explicit pmax/psum merge below is what
     # establishes replication of the output
     if quant:
         ksc, vsc = scales
-        fn = shard_map(
+        fn = jax.shard_map(
             local, mesh=mesh,
             in_specs=(rep_spec, rep_spec, rep_spec, cache_spec, cache_spec,
                       cache_spec, cache_spec, len_spec),
             out_specs=(rep_spec, cache_spec, cache_spec, cache_spec,
                        cache_spec),
-            check_rep=False,
+            check_vma=False,
         )
         out, k2, v2, ks2, vs2 = fn(q, k_new, v_new, k_cache, v_cache,
                                    ksc, vsc, lengths)
@@ -171,12 +169,12 @@ def decode_attention_sharded(
     def local_noq(q_l, kn, vn, ck, cv, length):
         return local(q_l, kn, vn, ck, cv, None, None, length)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_noq, mesh=mesh,
         in_specs=(rep_spec, rep_spec, rep_spec, cache_spec, cache_spec,
                   len_spec),
         out_specs=(rep_spec, cache_spec, cache_spec),
-        check_rep=False,
+        check_vma=False,
     )
     out, k2, v2 = fn(q, k_new, v_new, k_cache, v_cache, lengths)
     return out, {"k": k2, "v": v2}
@@ -281,12 +279,12 @@ def decode_attention_sharded_paged(
     # writes by the full batch keep every data-replica identical
     if quant:
         ksc, vsc = scales
-        fn = shard_map(
+        fn = jax.shard_map(
             local, mesh=mesh,
             in_specs=(rep4, rep4, rep4, pool_spec, pool_spec, pool_spec,
                       pool_spec, P(None), P(None, None), P(None)),
             out_specs=(rep4, pool_spec, pool_spec, pool_spec, pool_spec),
-            check_rep=False,
+            check_vma=False,
         )
         out, k2, v2, ks2, vs2 = fn(q, k_new, v_new, k_pool, v_pool,
                                    ksc, vsc, lengths, page_table, mask)
@@ -295,12 +293,12 @@ def decode_attention_sharded_paged(
     def local_noq(q_l, kn, vn, kp, vp, length, table, wmask):
         return local(q_l, kn, vn, kp, vp, None, None, length, table, wmask)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_noq, mesh=mesh,
         in_specs=(rep4, rep4, rep4, pool_spec, pool_spec, P(None),
                   P(None, None), P(None)),
         out_specs=(rep4, pool_spec, pool_spec),
-        check_rep=False,
+        check_vma=False,
     )
     out, k2, v2 = fn(q, k_new, v_new, k_pool, v_pool, lengths,
                      page_table, mask)

@@ -182,11 +182,11 @@ def _mesh_ctx(mesh: bool):
     — still green, by the balance-not-correctness contract."""
     import contextlib
 
+    from repro.launch.mesh import make_mesh
     from repro.parallel.hints import use_mesh
     if not mesh:
         return contextlib.nullcontext()
-    return use_mesh(jax.make_mesh((1, jax.device_count()),
-                                  ("data", "model")))
+    return use_mesh(make_mesh((1, jax.device_count()), ("data", "model")))
 
 
 # oracle executables close over their cfg, so compile caches are shared

@@ -15,10 +15,11 @@ import jax
 import numpy as np
 import pytest
 
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.parallel import sharding as shd
-from repro.parallel.hints import hint, active_mesh
+from repro.parallel.hints import active_mesh, hint, use_mesh
 
 _WORKER = textwrap.dedent("""
     import os
@@ -31,11 +32,12 @@ _WORKER = textwrap.dedent("""
     from repro.models import api, moe
     from repro.parallel import sharding as shd
     from repro.parallel.hints import use_mesh
+    from repro.launch.mesh import make_mesh
     from repro.optim.adamw import AdamW
     from repro.train import trainer
 
     out = {}
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
 
     # --- shard_map MoE vs local MoE numerics -----------------------------
     cfg = get_smoke_config("mixtral-8x22b", d_ff=64, dtype=jnp.float32)
@@ -120,7 +122,7 @@ class TestDistributedTrainStep:
 class TestShardingRules:
     def _mesh(self):
         # single-device "mesh" is enough to compute specs
-        return jax.make_mesh((1, 1), ("data", "model"))
+        return make_mesh((1, 1), ("data", "model"))
 
     def test_col_row_parallel_specs(self):
         mesh = self._mesh()
@@ -153,11 +155,11 @@ class TestShardingRules:
         assert specs["mlp"]["up"] == P(None, "model")
 
     def test_indivisible_dims_drop_sharding(self):
-        mesh = jax.make_mesh((1,), ("model",))
+        mesh = make_mesh((1,), ("model",))
         tree = {"attn": {"wq": jax.ShapeDtypeStruct((100, 7), np.float32)}}
         specs = shd.param_specs(tree, mesh, "serve")
         assert specs["attn"]["wq"] == P(None, "model")  # 7 % 1 == 0 fine
-        mesh2 = jax.make_mesh((1, 1), ("data", "model"))
+        mesh2 = make_mesh((1, 1), ("data", "model"))
         # legalization keeps only divisible axes
         t2 = {"attn": {"wq": jax.ShapeDtypeStruct((3, 5), np.float32)}}
         s2 = shd.param_specs(t2, mesh2, "train")
@@ -188,6 +190,24 @@ class TestMeshConstruction:
         from repro.launch.mesh import make_host_mesh
         mesh = make_host_mesh()
         assert set(mesh.axis_names) == {"data", "model"}
+        assert all(t == AxisType.Auto for t in mesh.axis_types)
+
+    @pytest.mark.parametrize("shape,axes", [
+        ((1,), ("model",)), ((1, 1), ("data", "model")),
+        ((1, 1, 1), ("pod", "data", "model"))])
+    def test_make_mesh_axes_are_auto(self, shape, axes):
+        mesh = make_mesh(shape, axes)
+        assert mesh.axis_names == axes
+        assert mesh.axis_types == (AxisType.Auto,) * len(axes)
+
+    def test_hint_constrains_under_helper_mesh(self):
+        """with_sharding_constraint inside jit is refused on Explicit axes
+        (jax.make_mesh's default in jax 0.9); the helper's mesh takes it."""
+        import jax.numpy as jnp
+        x = jnp.ones((4, 8))
+        with use_mesh(make_mesh((1, 1), ("data", "model"))):
+            y = jax.jit(lambda a: hint(a * 2, "batch", "heads"))(x)
+        np.testing.assert_array_equal(np.asarray(y), 2 * np.ones((4, 8)))
 
 
 class TestInt8KVCache:
@@ -232,7 +252,8 @@ class TestInt8KVCache:
             from repro.configs import get_smoke_config
             from repro.models import api
             from repro.parallel.hints import use_mesh
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 2), ("data", "model"))
             cfg = get_smoke_config("qwen3-8b", kv_quant="int8")
             params = api.init_params(cfg, jax.random.PRNGKey(0))
             cache = api.init_cache(cfg, 8, 64)

@@ -130,7 +130,8 @@ class TestDispatch:
         assert ffn_fused.fused_variant(
             _rand((1, d)), sp[0], sp[1], dn, "swiglu", None, None) is None
         x = _rand((3, d), seed=5)
-        got = ops.ffn_w4a16(x, sp[0], sp[1], dn, impl="pallas")
+        with pytest.warns(ops.KernelFallbackWarning):
+            got = ops.ffn_w4a16(x, sp[0], sp[1], dn, impl="pallas")
         want = ops.ffn_w4a16(x, sp[0], sp[1], dn, impl="ref")
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32), **TOL)
@@ -144,7 +145,8 @@ class TestDispatch:
         assert ffn_fused.fused_variant(
             _rand((1, d)), sp[0], sp[1], dn16, "swiglu", None, None) is None
         x = _rand((2, d), seed=14)
-        got = ops.ffn_w4a16(x, sp[0], sp[1], dn16, impl="pallas")
+        with pytest.warns(ops.KernelFallbackWarning):
+            got = ops.ffn_w4a16(x, sp[0], sp[1], dn16, impl="pallas")
         want = ops.ffn_w4a16(x, sp[0], sp[1], dn16, impl="ref")
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32), **TOL)
@@ -170,7 +172,8 @@ class TestDispatch:
         assert ffn_fused.fused_variant(
             _rand((1, d)), gq, uq, dq, "swiglu", None, None) is None
         x = _rand((4, d), seed=8)
-        got = ops.ffn_w4a16(x, gq, uq, dq, impl="pallas")  # falls back
+        with pytest.warns(ops.KernelFallbackWarning):
+            got = ops.ffn_w4a16(x, gq, uq, dq, impl="pallas")
         want = ops.ffn_w4a16(x, gq, uq, dq, impl="ref")
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32), **TOL)

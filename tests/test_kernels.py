@@ -42,6 +42,18 @@ class TestW4A16Kernel:
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(want, np.float32), **_tol(dtype))
 
+    @pytest.mark.parametrize("tokens", [3, 70])
+    def test_ragged_out_tile(self, tokens):
+        """out_features not a multiple of block_out (a 151936-wide lm_head
+        is 128 x a prime): the last out tile is a partial block."""
+        x = _rand((tokens, 256), seed=tokens)
+        qt = quantize(_rand((256, 640), seed=8, dtype=jnp.float32))
+        got = w4a16_matmul_pallas(x, qt, block_tokens=64, block_out=256)
+        want = ref.w4a16_matmul_ref(x, qt)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            **_tol(jnp.bfloat16))
+
     def test_batched_lead_dims(self):
         x = _rand((2, 4, 16, 256), seed=3)
         qt = quantize(_rand((256, 128), seed=9, dtype=jnp.float32))
@@ -279,3 +291,23 @@ class TestSlstmScanKernel:
         h, dh = 4, 512
         resident = h * dh * 4 * dh * 2   # bf16
         assert resident <= 16 * 2**20 * 0.75
+
+
+def test_importing_the_package_takes_no_device():
+    """Importing must not initialise a JAX backend: on a TPU host the first
+    backend probe takes the chip, and a serving process imported by another
+    process would then hold it.  The kernels probe at their first call."""
+    import os
+    import subprocess
+    import sys
+    code = (
+        "import importlib, pkgutil, repro\n"
+        "from jax._src import xla_bridge\n"
+        "for m in pkgutil.walk_packages(repro.__path__, 'repro.'):\n"
+        "    if not m.name.endswith('dryrun'):\n"  # forces host devices
+        "        importlib.import_module(m.name)\n"
+        "assert not xla_bridge.backends_are_initialized()\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]

@@ -22,6 +22,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
+from repro.launch.mesh import make_mesh
 from repro.models import api
 from repro.parallel import decode_attn
 from repro.parallel.hints import use_mesh
@@ -88,7 +89,7 @@ class TestBlockAllocatorHomes:
 
 class TestPagedHomes:
     def _mesh(self):
-        return jax.make_mesh((1, 1), ("data", "model"))
+        return make_mesh((1, 1), ("data", "model"))
 
     def test_no_mesh_is_unsharded(self):
         assert decode_attn.paged_homes(None, 4, 40) == 1
@@ -134,7 +135,7 @@ def test_paged_dispatch_reaches_mesh_gate(monkeypatch):
     params = api.init_params(cfg, jax.random.PRNGKey(0))
     cache = api.init_cache(cfg, 2, 32)
     tok = jnp.zeros((2, 1), jnp.int32)
-    with use_mesh(jax.make_mesh((1, 1), ("data", "model"))):
+    with use_mesh(make_mesh((1, 1), ("data", "model"))):
         api.decode_step(cfg, params, cache, tok,
                         jnp.asarray([3, 5], jnp.int32))
     paged_calls = [c for c in seen if c["paged"]]
@@ -165,7 +166,7 @@ def test_paged_sharded_one_shard_matches_single_device():
 
     l_ref, c_ref = api.decode_step(cfg, params, cache, tok, lengths,
                                    page_table=table, write_mask=wmask)
-    with use_mesh(jax.make_mesh((1, 1), ("data", "model"))):
+    with use_mesh(make_mesh((1, 1), ("data", "model"))):
         l_sh, c_sh = jax.jit(lambda p, c, t, l, pt, wm: api.decode_step(
             cfg, p, c, t, l, page_table=pt, write_mask=wm))(
             params, cache, tok, lengths, table, wmask)
@@ -193,9 +194,10 @@ _WORKER = textwrap.dedent("""
     from repro.configs import get_smoke_config
     from repro.models import api
     from repro.parallel.hints import use_mesh
+    from repro.launch.mesh import make_mesh
 
     out = {}
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_mesh((1, 8), ("data", "model"))
     rng = np.random.default_rng(0)
 
     def pool_ok(a, b):

@@ -293,3 +293,34 @@ class TestQuantizeModel:
         b = np.asarray(q_logits, np.float32).ravel()
         corr = np.corrcoef(a, b)[0, 1]
         assert corr > 0.9
+
+    @pytest.mark.parametrize("arch", ["qwen-7b", "chatglm-6b"])
+    def test_init_quantized_equals_quantize_model(self, arch):
+        """Leaf by leaf on the host, packed layer by layer: the same tree
+        as packing the whole jitted init at once."""
+        cfg = get_smoke_config(arch, d_model=512, d_ff=1024, n_heads=4,
+                               n_kv_heads=2, head_dim=128)
+        init = lambda k: api.init_params(cfg, k)
+        key = jax.random.PRNGKey(3)
+        want = cc.quantize_model(jax.jit(init)(key), "strategy2")
+        got = cc.init_quantized(init, key, "strategy2")
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        assert isinstance(got["blocks"]["mlp"]["gate"], SparseQuantizedTensor)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_init_quantized_packs_expert_stacks(self):
+        """(L, E, d, f) expert leaves: lax.map over layers, vmap over
+        experts.  Packing is per (d, f) matrix either way; a division fused
+        differently may land a value on the other side of a rounding tie,
+        so the nibbles are compared as near-equal (one tie in 10^5)."""
+        cfg = get_smoke_config("mixtral-8x22b")
+        init = lambda k: api.init_params(cfg, k)
+        key = jax.random.PRNGKey(0)
+        want = cc.quantize_model(jax.jit(init)(key), "strategy2")
+        got = cc.init_quantized(init, key, "strategy2")
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert a.shape == b.shape and a.dtype == b.dtype
+            assert np.mean(np.asarray(a) != np.asarray(b)) < 1e-4
