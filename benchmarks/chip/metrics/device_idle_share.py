@@ -1,0 +1,6 @@
+"""Share of the traced sub-window in which no operation ran on the device:
+1 - union of device-op intervals / traced window."""
+
+
+def read(run):
+    return None if run.trace is None else run.trace["idle_share"] * 100.0
