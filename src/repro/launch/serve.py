@@ -160,7 +160,8 @@ def main() -> None:
         print(f"NOT drained: truncated={done.truncated} "
               f"stalled={done.stalled} in_flight={done.in_flight} "
               f"queued={done.queued}")
-    print("summary:", Engine.summarize(done))
+    summary = Engine.summarize(done)
+    print("summary:", summary)
     r = engine.resilience_stats()
     print(f"resilience: {r['preemptions']} preemptions "
           f"(bound {r['max_preemptions']}/req), "
@@ -174,9 +175,13 @@ def main() -> None:
               f"{d['snapshot_every'] or 'startup-only'} ticks), "
               f"journal={'on' if d['journal'] else 'off'} — recover with "
               f"--restore --snapshot-dir {d['snapshot_dir']}")
+    padding = (1 - engine.positions_live / engine.positions_computed
+               if engine.positions_computed else 0.0)
     print(f"scheduler: {engine.steps} ticks, {engine.dispatches} dispatches "
           f"(1 per tick, {engine.mixed_ticks} mixed), slot occupancy "
-          f"{engine.slot_occupancy:.2f}")
+          f"{engine.slot_occupancy:.2f}, padding share {padding:.2f}, "
+          f"admission wait p50 {summary.get('queue_wait_p50_s', 0.0):.4f} s "
+          f"p99 {summary.get('queue_wait_p99_s', 0.0):.4f} s")
     if engine.spec_k:
         s = engine.spec_stats()
         print(f"speculation: K={s['spec_k']} drafter={args.drafter} — "

@@ -269,6 +269,19 @@ def _prefill_chunked(cfg: ModelConfig, params: Params, tokens: jax.Array,
     return logits, cache
 
 
+# The serving steps name their parts with ``jax.named_scope`` (embed,
+# attention with its KV write, ffn, lm_head): metadata only, so a profiler
+# trace can put each device op down to its part of the step.
+
+def _ffn(cfg: ModelConfig, bp: Params, x: jax.Array) -> jax.Array:
+    """A block's FFN on the residual stream ``x``, its norm included."""
+    with jax.named_scope("ffn"):
+        inner = layers.apply_norm(cfg, bp["ln_mlp"], x)
+        if cfg.is_moe:
+            return moe.moe_apply(cfg, bp["moe"], inner)[0]
+        return layers.mlp_apply(cfg, bp["mlp"], inner)
+
+
 def mixed_step(cfg: ModelConfig, params: Params, cache: Params,
                tokens: jax.Array, lengths, q_lens, *, page_table=None,
                all_logits: bool = False):
@@ -290,7 +303,8 @@ def mixed_step(cfg: ModelConfig, params: Params, cache: Params,
     (positions past ``q_lens[b]-1`` are padding garbage; callers mask).
     """
     b, c = tokens.shape
-    x = embed_tokens(cfg, params, tokens)
+    with jax.named_scope("embed"):
+        x = embed_tokens(cfg, params, tokens)
     lengths = jnp.asarray(lengths, jnp.int32)
     q_lens = jnp.asarray(q_lens, jnp.int32)
     pos = lengths[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
@@ -299,28 +313,26 @@ def mixed_step(cfg: ModelConfig, params: Params, cache: Params,
 
     def body(carry, inp):
         bp, layer_cache = inp
-        h, new_cache = attention.attn_mixed(
-            cfg, bp["attn"], layers.apply_norm(cfg, bp["ln_attn"], carry),
-            pos, layer_cache, lengths, q_lens, page_table=page_table)
+        with jax.named_scope("attention"):
+            h, new_cache = attention.attn_mixed(
+                cfg, bp["attn"], layers.apply_norm(cfg, bp["ln_attn"], carry),
+                pos, layer_cache, lengths, q_lens, page_table=page_table)
         x2 = carry + h
-        inner = layers.apply_norm(cfg, bp["ln_mlp"], x2)
-        if cfg.is_moe:
-            f, _ = moe.moe_apply(cfg, bp["moe"], inner)
-        else:
-            f = layers.mlp_apply(cfg, bp["mlp"], inner)
-        return x2 + f, new_cache
+        return x2 + _ffn(cfg, bp, x2), new_cache
 
     x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache))
-    if all_logits:
-        # verify surface: every chunk position reaches the LM head
-        x = layers.apply_norm(cfg, params["ln_f"], x)
-        return unembed(cfg, params, x), new_cache
-    # only each row's last live position reaches the LM head (C-fold cheaper
-    # than unembedding the full chunk; mid-prefill rows need just this one)
-    idx = jnp.clip(q_lens - 1, 0, c - 1)
-    x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)
-    x_last = layers.apply_norm(cfg, params["ln_f"], x_last)
-    return unembed(cfg, params, x_last)[:, 0], new_cache
+    with jax.named_scope("lm_head"):
+        if all_logits:
+            # verify surface: every chunk position reaches the LM head
+            x = layers.apply_norm(cfg, params["ln_f"], x)
+            return unembed(cfg, params, x), new_cache
+        # only each row's last live position reaches the LM head (C-fold
+        # cheaper than unembedding the full chunk; mid-prefill rows need
+        # just this one)
+        idx = jnp.clip(q_lens - 1, 0, c - 1)
+        x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)
+        x_last = layers.apply_norm(cfg, params["ln_f"], x_last)
+        return unembed(cfg, params, x_last)[:, 0], new_cache
 
 
 def decode_step(cfg: ModelConfig, params: Params, cache: Params,
@@ -331,7 +343,8 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Params,
     Paged layout: ``page_table`` routes the K/V scatter; ``write_mask``
     (B,) bool sends masked rows' writes to the null block."""
     b = tokens.shape[0]
-    x = embed_tokens(cfg, params, tokens)
+    with jax.named_scope("embed"):
+        x = embed_tokens(cfg, params, tokens)
     lengths = jnp.asarray(lengths)
     pos = (lengths - 1).reshape(-1, 1) * jnp.ones((b, 1), jnp.int32)
     if cfg.rope_type == "mrope":
@@ -339,18 +352,15 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Params,
 
     def body(carry, inp):
         bp, layer_cache = inp
-        h, new_cache = attention.attn_decode(
-            cfg, bp["attn"], layers.apply_norm(cfg, bp["ln_attn"], carry),
-            pos, layer_cache, lengths, page_table=page_table,
-            write_mask=write_mask)
+        with jax.named_scope("attention"):
+            h, new_cache = attention.attn_decode(
+                cfg, bp["attn"], layers.apply_norm(cfg, bp["ln_attn"], carry),
+                pos, layer_cache, lengths, page_table=page_table,
+                write_mask=write_mask)
         x2 = carry + h
-        inner = layers.apply_norm(cfg, bp["ln_mlp"], x2)
-        if cfg.is_moe:
-            f, _ = moe.moe_apply(cfg, bp["moe"], inner)
-        else:
-            f = layers.mlp_apply(cfg, bp["mlp"], inner)
-        return x2 + f, new_cache
+        return x2 + _ffn(cfg, bp, x2), new_cache
 
     x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache))
-    x = layers.apply_norm(cfg, params["ln_f"], x)
-    return unembed(cfg, params, x)[:, 0], new_cache
+    with jax.named_scope("lm_head"):
+        x = layers.apply_norm(cfg, params["ln_f"], x)
+        return unembed(cfg, params, x)[:, 0], new_cache

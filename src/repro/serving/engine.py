@@ -169,6 +169,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.compiler import CompileCache, TokenBuckets
 from repro.models import api
@@ -194,6 +195,18 @@ class _PrefixPlan:
 # loops running back to queued; ``done`` stays True exactly on terminals)
 TERMINAL_STATES = ("done", "error", "cancelled", "deadline_missed")
 
+# host spans of one pass of ``Engine.run``'s loop, parent first, then its
+# children in the order they run; fetch_logits and journal appear only in
+# ticks that copy logits to the host or touch audit, journal or snapshot.
+# They are ``jax.profiler.TraceAnnotation``s: on the profiler's clock, in
+# the same trace as the device's ops, while a trace runs, and about a
+# microsecond each when none does.  ``engine.tick`` carries ``kind``
+# (mixed/decode), ``w`` (the bucket width, 1 for decode), ``live`` (rows
+# holding a request) and ``live_positions`` (positions that advance a row).
+SPANS = ("engine.tick", "engine.admit", "engine.plan", "engine.prepare",
+         "engine.dispatch", "engine.sync", "engine.fetch_logits",
+         "engine.consume", "engine.journal")
+
 
 @dataclasses.dataclass
 class Request:
@@ -213,6 +226,7 @@ class Request:
     folded: int = 0                  # output tokens already folded into
     #                                  prompt by earlier preemptions
     submitted_at: float = 0.0
+    admitted_at: float | None = None  # first admission (kept on preemption)
     first_token_at: float | None = None
     finished_at: float | None = None
     token_times: list = dataclasses.field(default_factory=list)
@@ -257,19 +271,21 @@ class _Slot:
         return self.req is not None and self.pos < len(self.req.prompt)
 
 
+# each executable's function has its own name, which jit gives the module
+# (``jit_mixed_step``, ...), so a profiler trace says which step ran
 def _mixed_executable(cfg: ModelConfig):
-    def fn(p, c, tokens, lengths, q_lens):
+    def mixed_step(p, c, tokens, lengths, q_lens):
         logits, new_c = api.mixed_step(cfg, p, c, tokens, lengths, q_lens)
         return jnp.argmax(logits, axis=-1), logits, new_c
-    return jax.jit(fn, donate_argnums=(1,))
+    return jax.jit(mixed_step, donate_argnums=(1,))
 
 
 def _mixed_executable_paged(cfg: ModelConfig):
-    def fn(p, c, tokens, lengths, q_lens, page_table):
+    def mixed_step(p, c, tokens, lengths, q_lens, page_table):
         logits, new_c = api.mixed_step(cfg, p, c, tokens, lengths, q_lens,
                                        page_table=page_table)
         return jnp.argmax(logits, axis=-1), logits, new_c
-    return jax.jit(fn, donate_argnums=(1,))
+    return jax.jit(mixed_step, donate_argnums=(1,))
 
 
 # adaptive-speculation cap: a slot whose drafts keep fully rejecting sits
@@ -285,7 +301,7 @@ def _mixed_executable_spec(cfg: ModelConfig, paged: bool):
     mixed ticks, so keys stay exactly ``("mixed", W)`` — the price is
     unembedding W positions instead of 1 on chunked-prefill ticks, which is
     what buys verify ticks their K-fold weight-stream amortization."""
-    def fn(p, c, tokens, lengths, q_lens, page_table=None):
+    def mixed_step_spec(p, c, tokens, lengths, q_lens, page_table=None):
         kw = {"page_table": page_table} if paged else {}
         logits, new_c = api.mixed_step(cfg, p, c, tokens, lengths, q_lens,
                                        all_logits=True, **kw)
@@ -295,40 +311,40 @@ def _mixed_executable_spec(cfg: ModelConfig, paged: bool):
         last_logits = jnp.take_along_axis(
             logits, idx[:, None, None], axis=1)[:, 0]
         return next_tok, last_logits, new_c, greedy
-    return jax.jit(fn, donate_argnums=(1,))
+    return jax.jit(mixed_step_spec, donate_argnums=(1,))
 
 
 def _decode_executable(cfg: ModelConfig):
-    def fn(p, c, tokens, lengths):
+    def decode_step(p, c, tokens, lengths):
         logits, new_c = api.decode_step(cfg, p, c, tokens, lengths)
         return jnp.argmax(logits, axis=-1), logits, new_c
-    return jax.jit(fn, donate_argnums=(1,))
+    return jax.jit(decode_step, donate_argnums=(1,))
 
 
 def _decode_executable_paged(cfg: ModelConfig):
     # write_mask keeps non-advancing rows (retired slots riding along, rows
     # between ticks) from writing through a stale/parked page table entry —
     # the paged replacement for "stale rows hide behind true-length masking"
-    def fn(p, c, tokens, lengths, page_table, write_mask):
+    def decode_step(p, c, tokens, lengths, page_table, write_mask):
         logits, new_c = api.decode_step(cfg, p, c, tokens, lengths,
                                         page_table=page_table,
                                         write_mask=write_mask)
         return jnp.argmax(logits, axis=-1), logits, new_c
-    return jax.jit(fn, donate_argnums=(1,))
+    return jax.jit(decode_step, donate_argnums=(1,))
 
 
 def _insert_executable(cfg: ModelConfig):
-    def fn(c, row, slot):
+    def insert_row(c, row, slot):
         return api.insert_request(cfg, c, row, slot)
     # donate the resident cache: the engine rebinds it on every call, so XLA
     # may update the slot in place instead of copying the whole cache
-    return jax.jit(fn, donate_argnums=(0,))
+    return jax.jit(insert_row, donate_argnums=(0,))
 
 
 def _admit_executable(cfg: ModelConfig, max_len: int):
-    def fn(p, frames):
+    def admit_row(p, frames):
         return api.request_cache(cfg, p, {"frames": frames}, max_len)
-    return jax.jit(fn)
+    return jax.jit(admit_row)
 
 
 class Engine:
@@ -479,6 +495,11 @@ class Engine:
         self.steps = 0
         self.dispatches = 0          # must equal steps: one dispatch per tick
         self.mixed_ticks = 0
+        # positions the step computed (batch x bucket width, or batch in a
+        # decode tick) and those that advanced a row: their ratio sizes the
+        # padding of the one-data-shape dispatch
+        self.positions_computed = 0
+        self.positions_live = 0
         self._occupancy_sum = 0.0
         # -- resilience layer (lifecycle, preemption, fault isolation) -------
         # max_preemptions bounds how many times ONE request may be evicted
@@ -654,9 +675,10 @@ class Engine:
         # one shape for every copy-on-write: (cache, src, dst) with traced
         # scalar block ids, donated cache — memoized under ("cow", 0)
         cfg = self.cfg
-        return jax.jit(
-            lambda c, s, d: api.copy_pool_block(cfg, c, s, d),
-            donate_argnums=(0,))
+
+        def copy_block(c, s, d):
+            return api.copy_pool_block(cfg, c, s, d)
+        return jax.jit(copy_block, donate_argnums=(0,))
 
     # -- paged-KV block accounting -------------------------------------------
 
@@ -1235,6 +1257,8 @@ class Engine:
             self.cache = insert(self.cache, row, np.int32(idx))
         self._admit_seq += 1
         req.status = "running"
+        if req.admitted_at is None:
+            req.admitted_at = self.clock()
         self._slots[idx] = _Slot(req=req, seq=self._admit_seq)
         if plan is not None:
             if plan.cow is not None:
@@ -1340,6 +1364,233 @@ class Engine:
             self._finish(req, completed)
             self._free_slot(idx)
 
+    # -- the phases of one tick (``run``; each runs under its own span) ------
+
+    def _refill(self, completed: list[Request]) -> list[int]:
+        """Tick steps 0-1: sweep deadlines and apply chaos preemption, then
+        refill free slots from the queue (a host-side lease — no prefill
+        dispatch), preempting bounded victims on a reservation shortfall or
+        for a higher-priority head.  Returns the live slot indices."""
+        # lifecycle sweeps: expired deadlines retire first (queued or
+        # mid-flight), then chaos may force-preempt a running row
+        self._sweep_deadlines(completed)
+        if self.chaos is not None and self.max_preemptions:
+            eligible = [i for i, s in enumerate(self._slots)
+                        if s.req is not None and
+                        s.req.preemptions < self.max_preemptions]
+            v = self.chaos.forced_preempt(eligible)
+            if v is not None:
+                self._preempt(v, requeue_front=True)
+        # 1. continuous refill: admit queued requests into free slots.
+        # Paged: strict-FIFO admission gated on the worst-case block
+        # reservation — shortfalls preempt the youngest/lowest-priority
+        # bounded victim (when allowed), else stall the head
+        for i in range(self.batch):
+            if self._slots[i].req is None and self._queue:
+                if not self._admit_head(i):
+                    break
+        # priority preemption: a waiting head that OUTRANKS a running
+        # request does not sit behind it just because the batch is full
+        while (self._queue and self.max_preemptions and
+               all(s.req is not None for s in self._slots)):
+            v = self._pick_victim(self._queue[0].priority, strict=True)
+            if v is None:
+                break
+            self._preempt(v)
+            if not self._admit_head(v):
+                break
+        return [i for i, s in enumerate(self._slots) if s.req is not None]
+
+    def _plan(self, live: list[int], sample) -> tuple:
+        """Tick step 2: co-schedule prompt chunks with decode and verify
+        rows and lease the blocks every advancing row writes.  Returns
+        (chunks, decoding, drafts)."""
+        chunks = self._schedule_chunks()
+        stall = (self.prefill_policy == "stall" and any(chunks))
+        decoding = [i for i in live
+                    if not self._slots[i].prefilling and not stall]
+        drafts = self._schedule_drafts(chunks, decoding, sample)
+        if self.chaos is not None and drafts:
+            # garbage drafts: same length (leases are sized by it), but
+            # possibly nonsense tokens — verify must reject losslessly
+            drafts = {i: self.chaos.garble_draft(d, self.cfg.vocab_size)
+                      for i, d in drafts.items()}
+        if self.paged:
+            # on-demand leases for every row advancing this tick (the
+            # admission reservation guarantees these succeed — verify rows
+            # stay inside it via the drafts' remaining-need cap)
+            for i, s in enumerate(self._slots):
+                if chunks[i]:
+                    self._lease_to(i, s.length + chunks[i])
+                elif i in decoding:
+                    self._lease_to(i, s.length + 1 + len(drafts.get(i, ())))
+        return chunks, decoding, drafts
+
+    def _mixed_args(self, chunks: list[int], decoding: list[int],
+                    drafts: dict) -> tuple[int, tuple]:
+        """The mixed tick's bucket width and device arguments: prompt
+        chunks + decode + verify rows, one dispatch."""
+        wide = max([max(chunks), 2] + [1 + len(d) for d in drafts.values()])
+        w = self.chunk_buckets.bucket(wide)
+        tokens = np.zeros((self.batch, w), np.int32)
+        lengths = np.zeros(self.batch, np.int32)
+        q_lens = np.zeros(self.batch, np.int32)
+        for i, s in enumerate(self._slots):
+            lengths[i] = s.length
+            if chunks[i]:
+                q_lens[i] = chunks[i]
+                tokens[i, :chunks[i]] = s.req.prompt[s.pos:s.pos + chunks[i]]
+            elif i in decoding:
+                d = drafts.get(i, ())
+                q_lens[i] = 1 + len(d)
+                tokens[i, 0] = s.last_token
+                if d:
+                    tokens[i, 1:1 + len(d)] = d
+        args = (jnp.asarray(tokens), jnp.asarray(lengths),
+                jnp.asarray(q_lens))
+        if self.paged:
+            args += (jnp.asarray(self._page_table),)
+        return w, args
+
+    def _decode_args(self, decoding: list[int]) -> tuple[int, tuple]:
+        """The pure-decode tick's width (1) and device arguments: the
+        classic executable, bit-identical to the batch-1 oracle; dead rows
+        ride along and their output is ignored."""
+        tokens = np.fromiter((s.last_token for s in self._slots),
+                             np.int32, self.batch).reshape(-1, 1)
+        lengths = np.fromiter(
+            (s.length + 1 if i in decoding else max(s.length, 1)
+             for i, s in enumerate(self._slots)),
+            np.int32, self.batch)
+        args = (jnp.asarray(tokens), jnp.asarray(lengths))
+        if self.paged:
+            adv = np.zeros(self.batch, bool)
+            adv[decoding] = True
+            args += (jnp.asarray(self._page_table), jnp.asarray(adv))
+        return 1, args
+
+    def _count_tick(self, live: list[int], chunks: list[int],
+                    decoding: list[int], drafts: dict, mixed: bool, w: int,
+                    positions: int) -> None:
+        """Counters of one dispatched tick."""
+        self.steps += 1
+        self.dispatches += 1
+        if mixed:
+            self.mixed_ticks += 1
+        if drafts:
+            self.spec_ticks += 1
+            self.spec_rows += len(drafts)
+            self.spec_drafted += sum(len(d) for d in drafts.values())
+        self.positions_computed += self.batch * w
+        self.positions_live += positions
+        self._occupancy_sum += len(live) / self.batch
+        if self.paged:
+            self.peak_pool_blocks = max(
+                self.peak_pool_blocks,
+                self.pool_blocks - self.alloc.n_free)
+        self.peak_resident_tokens = max(
+            self.peak_resident_tokens,
+            sum(self._slots[i].length + chunks[i] + (i in decoding) +
+                len(drafts.get(i, ()))
+                for i in live))
+
+    def _fetch_logits(self, logits, live: list[int], chunks: list[int],
+                      decoding: list[int]) -> tuple[np.ndarray, set[int]]:
+        """Copy the tick's logits to the host for the ``sample`` hook and
+        the row checks; returns them with the rows whose logits are not
+        finite (``check_finite``; chaos may poison rows first)."""
+        logits_np = np.asarray(logits)
+        advancing = [i for i in live if chunks[i] or i in decoding]
+        if self.chaos is not None:
+            hit = self.chaos.corrupt_rows(advancing)
+            if hit:
+                logits_np = logits_np.copy()  # device arrays read-only
+                for i in hit:
+                    logits_np[i] = np.nan
+        bad: set[int] = set()
+        if self.check_finite:
+            bad = {i for i in advancing
+                   if not np.isfinite(logits_np[i]).all()}
+        return logits_np, bad
+
+    def _consume(self, live: list[int], chunks: list[int],
+                 decoding: list[int], drafts: dict, bad: set[int],
+                 next_np: np.ndarray, greedy_np, logits_np,
+                 completed: list[Request], sample) -> None:
+        """Tick step 3: advance cursors, emit tokens, retire finished rows;
+        faulted rows quarantine here, the rest are untouched."""
+        for i in list(live):
+            slot = self._slots[i]
+            if slot.req is None:
+                continue        # freed earlier this tick
+            if i in bad:
+                self._fault_row(i, "non-finite logits", completed)
+                continue
+            if chunks[i]:
+                slot.pos += chunks[i]
+                slot.length += chunks[i]
+                if slot.pos == len(slot.req.prompt):
+                    if self.prefix is not None:
+                        # fully-written prompt blocks join the cache
+                        self._cache_prompt(i)
+                    # final chunk: this row's logits are its first token
+                    if sample is None:
+                        tok = int(next_np[i])
+                    else:
+                        tok = self._safe_sample(i, sample, logits_np,
+                                                completed)
+                        if tok is None or self._slots[i].req is None:
+                            # hook threw (row quarantined) or cancelled
+                            # this very row mid-sample
+                            continue
+                    self._emit(i, tok, completed, first=True)
+            elif i in drafts:
+                # verify row: accept the longest draft prefix agreeing
+                # with the model's greedy tokens, emit the model's token
+                # at each accepted position PLUS the first disagreement
+                # (so a verify tick never emits less than plain decode),
+                # then roll back the rejected tail
+                d = drafts[i]
+                g = greedy_np[i]
+                a = 0
+                while a < len(d) and d[a] == int(g[a]):
+                    a += 1
+                self.spec_accepted += a
+                if a:               # productive row: speculate freely
+                    self._draft_wait[i] = self._draft_penalty[i] = 0
+                else:               # full miss: back off exponentially
+                    self._draft_penalty[i] = min(
+                        max(self._draft_penalty[i] * 2, 1),
+                        _DRAFT_BACKOFF_MAX)
+                    self._draft_wait[i] = self._draft_penalty[i]
+                base = slot.length
+                freed = False
+                for j in range(a + 1):
+                    # emit-time length matches the sequential schedule:
+                    # token j corresponds to cache length base + 1 + j,
+                    # so the max_len/eos/max_new stop rules fire at
+                    # exactly the oracle's token
+                    slot.length = base + 1 + j
+                    self._emit(i, int(g[j]), completed, first=False)
+                    if self._slots[i].req is None:
+                        freed = True   # finished: _free_slot did cleanup
+                        break
+                if not freed and a < len(d):
+                    self.spec_rewinds += 1
+                    self._rewind_slot(i, base + 1 + a)
+            elif i in decoding:
+                slot.length += 1
+                if sample is None:
+                    tok = int(next_np[i])
+                else:
+                    tok = self._safe_sample(i, sample, logits_np,
+                                            completed)
+                    if tok is None or self._slots[i].req is None:
+                        # hook threw (row quarantined) or cancelled
+                        # this very row mid-sample
+                        continue
+                self._emit(i, tok, completed, first=False)
+
     def run(self, *, max_steps: int = 10_000,
             sample: Callable | None = None) -> "RunResult":
         """Drain the queue; returns a ``RunResult`` (a list of the requests
@@ -1361,250 +1612,91 @@ class Engine:
         stalled = False                # engine's lifetime
         idle = 0                       # consecutive no-row no-admission ticks
         while self.steps - start_steps < max_steps:
-            # 0. chaos process death fires at the TOP of the tick, after the
-            # previous tick's journal batch was fsync'd — so a kill can lose
-            # at most un-dispatched work, never an emitted token (getattr:
-            # older monkeys/test doubles predate the kill seam)
-            if self.chaos is not None:
-                kill = getattr(self.chaos, "maybe_kill", None)
-                if kill is not None:
-                    kill()
-            # lifecycle sweeps: expired deadlines retire first (queued
-            # or mid-flight), then chaos may force-preempt a running row
-            self._sweep_deadlines(completed)
-            if self.chaos is not None and self.max_preemptions:
-                eligible = [i for i, s in enumerate(self._slots)
-                            if s.req is not None and
-                            s.req.preemptions < self.max_preemptions]
-                v = self.chaos.forced_preempt(eligible)
-                if v is not None:
-                    self._preempt(v, requeue_front=True)
-            # 1. continuous refill: admit queued requests into free slots.
-            # Paged: strict-FIFO admission gated on the worst-case block
-            # reservation — shortfalls preempt the youngest/lowest-priority
-            # bounded victim (when allowed), else stall the head
-            for i in range(self.batch):
-                if self._slots[i].req is None and self._queue:
-                    if not self._admit_head(i):
+            with TraceAnnotation("engine.tick") as tick:
+                # 0. chaos process death fires at the TOP of the tick, after
+                # the previous tick's journal batch was fsync'd — so a kill
+                # can lose at most un-dispatched work, never an emitted token
+                # (getattr: older monkeys/test doubles predate the kill seam)
+                if self.chaos is not None:
+                    kill = getattr(self.chaos, "maybe_kill", None)
+                    if kill is not None:
+                        kill()
+                with TraceAnnotation("engine.admit"):
+                    live = self._refill(completed)
+                if not live:
+                    stalled = bool(self._queue)
+                    if not stalled:
+                        break      # queue drained and no row in flight
+                    # work is queued but nothing runs.  Without chaos this is
+                    # permanent (submit bounds worst case by the pool, and
+                    # with no live rows cache eviction can always free the
+                    # rest) — under injection a denial is transient, so
+                    # retry, bounded by max_steps idle ticks
+                    idle += 1
+                    if self.chaos is None or idle >= max_steps:
                         break
-            # priority preemption: a waiting head that OUTRANKS a running
-            # request does not sit behind it just because the batch is full
-            while (self._queue and self.max_preemptions and
-                   all(s.req is not None for s in self._slots)):
-                v = self._pick_victim(self._queue[0].priority, strict=True)
-                if v is None:
-                    break
-                self._preempt(v)
-                if not self._admit_head(v):
-                    break
-            live = [i for i, s in enumerate(self._slots) if s.req is not None]
-            if not live:
-                stalled = bool(self._queue)
-                if not stalled:
-                    break          # queue drained and no row in flight
-                # work is queued but nothing runs.  Without chaos this is
-                # permanent (submit bounds worst case by the pool, and with
-                # no live rows cache eviction can always free the rest) —
-                # under injection a denial is transient, so retry, bounded
-                # by max_steps idle ticks
-                idle += 1
-                if self.chaos is None or idle >= max_steps:
-                    break
-                continue
-            idle = 0
-            chunks = self._schedule_chunks()
-            stall = (self.prefill_policy == "stall" and any(chunks))
-            decoding = [i for i in live
-                        if not self._slots[i].prefilling and not stall]
-            drafts = self._schedule_drafts(chunks, decoding, sample)
-            if self.chaos is not None and drafts:
-                # garbage drafts: same length (leases are sized by it), but
-                # possibly nonsense tokens — verify must reject losslessly
-                drafts = {i: self.chaos.garble_draft(d, self.cfg.vocab_size)
-                          for i, d in drafts.items()}
-            if self.paged:
-                # on-demand leases for every row advancing this tick (the
-                # admission reservation guarantees these succeed — verify
-                # rows stay inside it via the drafts' remaining-need cap)
-                for i, s in enumerate(self._slots):
-                    if chunks[i]:
-                        self._lease_to(i, s.length + chunks[i])
-                    elif i in decoding:
-                        self._lease_to(
-                            i, s.length + 1 + len(drafts.get(i, ())))
-                page_table = jnp.asarray(self._page_table)
-
-            greedy_np = None
-            if any(chunks) or drafts:
-                # 2a. mixed tick: prompt chunks + decode + verify rows,
-                # one dispatch
-                wide = max([max(chunks), 2] +
-                           [1 + len(d) for d in drafts.values()])
-                w = self.chunk_buckets.bucket(wide)
-                tokens = np.zeros((self.batch, w), np.int32)
-                lengths = np.zeros(self.batch, np.int32)
-                q_lens = np.zeros(self.batch, np.int32)
-                for i, s in enumerate(self._slots):
-                    lengths[i] = s.length
-                    if chunks[i]:
-                        q_lens[i] = chunks[i]
-                        tokens[i, :chunks[i]] = \
-                            s.req.prompt[s.pos:s.pos + chunks[i]]
-                    elif i in decoding:
-                        d = drafts.get(i, ())
-                        q_lens[i] = 1 + len(d)
-                        tokens[i, 0] = s.last_token
-                        if d:
-                            tokens[i, 1:1 + len(d)] = d
-                fn = self.cache_compiles.get("mixed", w, self._build_mixed)
-                args = (jnp.asarray(tokens), jnp.asarray(lengths),
-                        jnp.asarray(q_lens))
-                if self.paged:
-                    args += (page_table,)
-                if self.spec_k:
-                    next_tok, logits, self.cache, greedy = fn(
-                        self.params, self.cache, *args)
-                    if drafts:
-                        greedy_np = np.asarray(greedy)
-                        self.spec_ticks += 1
-                        self.spec_rows += len(drafts)
-                        self.spec_drafted += sum(
-                            len(d) for d in drafts.values())
-                else:
-                    next_tok, logits, self.cache = fn(
-                        self.params, self.cache, *args)
-                self.mixed_ticks += 1
-            else:
-                # 2b. pure-decode tick: the classic executable (bit-identical
-                # to the batch-1 oracle; dead rows ride along, output ignored)
-                tokens = np.fromiter((s.last_token for s in self._slots),
-                                     np.int32, self.batch).reshape(-1, 1)
-                lengths = np.fromiter(
-                    (s.length + 1 if i in decoding else max(s.length, 1)
-                     for i, s in enumerate(self._slots)),
-                    np.int32, self.batch)
-                fn = self.cache_compiles.get("decode", self.batch,
-                                             self._build_decode)
-                args = (jnp.asarray(tokens), jnp.asarray(lengths))
-                if self.paged:
-                    adv = np.zeros(self.batch, bool)
-                    adv[decoding] = True
-                    args += (page_table, jnp.asarray(adv))
-                next_tok, logits, self.cache = fn(
-                    self.params, self.cache, *args)
-
-            self.steps += 1
-            self.dispatches += 1
-            self._occupancy_sum += len(live) / self.batch
-            if self.paged:
-                self.peak_pool_blocks = max(
-                    self.peak_pool_blocks,
-                    self.pool_blocks - self.alloc.n_free)
-            self.peak_resident_tokens = max(
-                self.peak_resident_tokens,
-                sum(self._slots[i].length + chunks[i] + (i in decoding) +
-                    len(drafts.get(i, ()))
-                    for i in live))
-            next_np = np.asarray(next_tok)
-            logits_np = None
-            if (sample is not None or self.check_finite or
-                    self.chaos is not None):
-                logits_np = np.asarray(logits)
-            advancing = [i for i in live if chunks[i] or i in decoding]
-            bad: set[int] = set()
-            if self.chaos is not None and logits_np is not None:
-                hit = self.chaos.corrupt_rows(advancing)
-                if hit:
-                    logits_np = logits_np.copy()  # device arrays read-only
-                    for i in hit:
-                        logits_np[i] = np.nan
-            if self.check_finite and logits_np is not None:
-                bad = {i for i in advancing
-                       if not np.isfinite(logits_np[i]).all()}
-
-            # 3. consume: advance cursors, emit tokens, retire finished
-            # rows; faulted rows quarantine here, the rest are untouched
-            for i in list(live):
-                slot = self._slots[i]
-                if slot.req is None:
-                    continue        # freed earlier this tick
-                if i in bad:
-                    self._fault_row(i, "non-finite logits", completed)
                     continue
-                if chunks[i]:
-                    slot.pos += chunks[i]
-                    slot.length += chunks[i]
-                    if slot.pos == len(slot.req.prompt):
-                        if self.prefix is not None:
-                            # fully-written prompt blocks join the cache
-                            self._cache_prompt(i)
-                        # final chunk: this row's logits are its first token
-                        if sample is None:
-                            tok = int(next_np[i])
-                        else:
-                            tok = self._safe_sample(i, sample, logits_np,
-                                                    completed)
-                            if tok is None or self._slots[i].req is None:
-                                # hook threw (row quarantined) or cancelled
-                                # this very row mid-sample
-                                continue
-                        self._emit(i, tok, completed, first=True)
-                elif i in drafts:
-                    # verify row: accept the longest draft prefix agreeing
-                    # with the model's greedy tokens, emit the model's token
-                    # at each accepted position PLUS the first disagreement
-                    # (so a verify tick never emits less than plain decode),
-                    # then roll back the rejected tail
-                    d = drafts[i]
-                    g = greedy_np[i]
-                    a = 0
-                    while a < len(d) and d[a] == int(g[a]):
-                        a += 1
-                    self.spec_accepted += a
-                    if a:               # productive row: speculate freely
-                        self._draft_wait[i] = self._draft_penalty[i] = 0
-                    else:               # full miss: back off exponentially
-                        self._draft_penalty[i] = min(
-                            max(self._draft_penalty[i] * 2, 1),
-                            _DRAFT_BACKOFF_MAX)
-                        self._draft_wait[i] = self._draft_penalty[i]
-                    base = slot.length
-                    freed = False
-                    for j in range(a + 1):
-                        # emit-time length matches the sequential schedule:
-                        # token j corresponds to cache length base + 1 + j,
-                        # so the max_len/eos/max_new stop rules fire at
-                        # exactly the oracle's token
-                        slot.length = base + 1 + j
-                        self._emit(i, int(g[j]), completed, first=False)
-                        if self._slots[i].req is None:
-                            freed = True   # finished: _free_slot did cleanup
-                            break
-                    if not freed and a < len(d):
-                        self.spec_rewinds += 1
-                        self._rewind_slot(i, base + 1 + a)
-                elif i in decoding:
-                    slot.length += 1
-                    if sample is None:
-                        tok = int(next_np[i])
+                idle = 0
+                with TraceAnnotation("engine.plan"):
+                    chunks, decoding, drafts = self._plan(live, sample)
+                # 2. one dispatch: mixed_step when any prompt chunk or draft
+                # rides along, the classic decode_step otherwise
+                mixed = bool(any(chunks) or drafts)
+                with TraceAnnotation("engine.prepare"):
+                    w, args = (self._mixed_args(chunks, decoding, drafts)
+                               if mixed else self._decode_args(decoding))
+                positions = (sum(chunks) + len(decoding) +
+                             sum(len(d) for d in drafts.values()))
+                tick.set_metadata(kind="mixed" if mixed else "decode", w=w,
+                                  live=len(live), live_positions=positions)
+                with TraceAnnotation("engine.dispatch"):
+                    if mixed:
+                        fn = self.cache_compiles.get("mixed", w,
+                                                     self._build_mixed)
                     else:
-                        tok = self._safe_sample(i, sample, logits_np,
-                                                completed)
-                        if tok is None or self._slots[i].req is None:
-                            # hook threw (row quarantined) or cancelled
-                            # this very row mid-sample
-                            continue
-                    self._emit(i, tok, completed, first=False)
-            if self.audit_every and self.steps % self.audit_every == 0:
-                self.audit()
-            # 4. durability: one fsync per tick batch, then maybe rotate a
-            # fresh snapshot — the snapshot sees every event the journal
-            # committed, so a kill between them loses nothing
-            if self._journal is not None:
-                self._journal.commit()
-            if (self.snapshot_dir is not None and self.snapshot_every and
-                    self.steps % self.snapshot_every == 0):
-                self.snapshot()
+                        fn = self.cache_compiles.get("decode", self.batch,
+                                                     self._build_decode)
+                    if mixed and self.spec_k:
+                        next_tok, logits, self.cache, greedy = fn(
+                            self.params, self.cache, *args)
+                    else:
+                        next_tok, logits, self.cache = fn(
+                            self.params, self.cache, *args)
+                self._count_tick(live, chunks, decoding, drafts, mixed, w,
+                                 positions)
+                with TraceAnnotation("engine.sync"):
+                    next_np = np.asarray(next_tok)
+                    greedy_np = np.asarray(greedy) if drafts else None
+                logits_np = None
+                bad: set[int] = set()
+                if (sample is not None or self.check_finite or
+                        self.chaos is not None):
+                    with TraceAnnotation("engine.fetch_logits"):
+                        logits_np, bad = self._fetch_logits(
+                            logits, live, chunks, decoding)
+                # 3. consume: advance cursors, emit tokens, retire finished
+                # rows; faulted rows quarantine here, the rest are untouched
+                with TraceAnnotation("engine.consume"):
+                    self._consume(live, chunks, decoding, drafts, bad,
+                                  next_np, greedy_np, logits_np, completed,
+                                  sample)
+                audit = bool(self.audit_every and
+                             self.steps % self.audit_every == 0)
+                snap = bool(self.snapshot_dir is not None and
+                            self.snapshot_every and
+                            self.steps % self.snapshot_every == 0)
+                if audit or snap or self._journal is not None:
+                    with TraceAnnotation("engine.journal"):
+                        if audit:
+                            self.audit()
+                        # 4. durability: one fsync per tick batch, then maybe
+                        # rotate a fresh snapshot — the snapshot sees every
+                        # event the journal committed, so a kill between them
+                        # loses nothing
+                        if self._journal is not None:
+                            self._journal.commit()
+                        if snap:
+                            self.snapshot()
         if self._journal is not None:
             self._journal.commit()
         in_flight = sum(s.req is not None for s in self._slots)
@@ -1652,8 +1744,12 @@ class Engine:
     def summarize(reqs: list[Request]) -> dict[str, float]:
         if not reqs:
             return {}
+        # TTFT is timed from submit, so it includes the queue wait
         ttft = [r.first_token_at - r.submitted_at for r in reqs
                 if r.first_token_at]
+        # queue wait: submit to the request's first admission into a row
+        wait = [r.admitted_at - r.submitted_at for r in reqs
+                if r.admitted_at is not None]
         # decode throughput: measured from the first token so queue-wait
         # does not pollute the device tokens/s number
         tps = [(len(r.output) - 1) /
@@ -1680,6 +1776,9 @@ class Engine:
             out["mean_ttft_s"] = float(np.mean(ttft))
             out["ttft_p50_s"] = float(np.percentile(ttft, 50))
             out["ttft_p99_s"] = float(np.percentile(ttft, 99))
+        if wait:
+            out["queue_wait_p50_s"] = float(np.percentile(wait, 50))
+            out["queue_wait_p99_s"] = float(np.percentile(wait, 99))
         if tps:
             out["mean_tokens_per_s"] = float(np.mean(tps))
         if itl:

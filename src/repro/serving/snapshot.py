@@ -70,6 +70,7 @@ _JOURNAL_RE = re.compile(r"journal_(\d+)\.jsonl$")
 # engine counters that round-trip verbatim through the host manifest
 _COUNTERS = (
     "steps", "dispatches", "mixed_ticks", "_occupancy_sum",
+    "positions_computed", "positions_live",
     "peak_pool_blocks", "peak_resident_tokens", "admission_stalls",
     "prefix_hits", "prefix_hit_tokens", "cow_copies", "prefix_evictions",
     "preemptions", "deadline_misses", "row_faults", "cancels", "audits",
@@ -221,6 +222,8 @@ def _dump_req(req: Request, now: float) -> dict:
         "preemptions": req.preemptions,
         "folded": req.folded,
         "age": age,
+        "admitted": (None if req.admitted_at is None
+                     else req.admitted_at - req.submitted_at),
         "ttft": (None if req.first_token_at is None
                  else req.first_token_at - req.submitted_at),
         "token_offsets": [t - req.submitted_at for t in req.token_times],
@@ -245,6 +248,9 @@ def _load_req(d: dict, now: float) -> Request:
     # restore, regardless of how long the process was dead
     req.deadline_s = (None if d["deadline_remaining"] is None
                       else d["deadline_remaining"] + d["age"])
+    # .get: a snapshot written before requests kept their admission time
+    req.admitted_at = (None if d.get("admitted") is None
+                       else req.submitted_at + d["admitted"])
     req.first_token_at = (None if d["ttft"] is None
                           else req.submitted_at + d["ttft"])
     req.token_times = [req.submitted_at + o for o in d["token_offsets"]]
@@ -442,7 +448,8 @@ def _load_host(eng: Engine, host: dict) -> None:
         eng.drafter.ngram_min = host["drafter"]["ngram_min"]
         eng.drafter.load(host["drafter"])
     for k in _COUNTERS:
-        setattr(eng, k, host["counters"][k])
+        # a counter newer than the snapshot keeps the fresh engine's zero
+        setattr(eng, k, host["counters"].get(k, getattr(eng, k)))
     eng._draft_wait = list(host["draft_wait"])
     eng._draft_penalty = list(host["draft_penalty"])
 
