@@ -180,6 +180,7 @@ def main() -> None:
     print(f"scheduler: {engine.steps} ticks, {engine.dispatches} dispatches "
           f"(1 per tick, {engine.mixed_ticks} mixed), slot occupancy "
           f"{engine.slot_occupancy:.2f}, padding share {padding:.2f}, "
+          f"{engine.logits_fetches} logits copies to the host, "
           f"admission wait p50 {summary.get('queue_wait_p50_s', 0.0):.4f} s "
           f"p99 {summary.get('queue_wait_p99_s', 0.0):.4f} s")
     if engine.spec_k:

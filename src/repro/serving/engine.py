@@ -129,7 +129,9 @@ dispatch instead:
   eviction can never starve anyone.  ``deadline_s`` requests are swept
   every tick (queued or running) once ``enforce_deadlines`` is on, and
   ``cancel(rid)`` retires a request at any point in the lifecycle.  Faults
-  stay inside their row: non-finite logits (``check_finite``) and a
+  stay inside their row: non-finite logits (``check_finite``: each step
+  returns a (B,) finiteness flag computed on the device and read with the
+  tokens, so only a ``sample`` hook's ticks copy logits to the host) and a
   throwing ``sample`` hook quarantine ONLY the offending slot (terminal
   ``status="error"``, blocks freed, allocator invariants intact) instead of
   propagating out of the tick — and a poisoned row's blocks are never
@@ -197,7 +199,8 @@ TERMINAL_STATES = ("done", "error", "cancelled", "deadline_missed")
 
 # host spans of one pass of ``Engine.run``'s loop, parent first, then its
 # children in the order they run; fetch_logits and journal appear only in
-# ticks that copy logits to the host or touch audit, journal or snapshot.
+# ticks that copy logits to the host (a ``sample`` hook is set) or touch
+# audit, journal or snapshot.
 # They are ``jax.profiler.TraceAnnotation``s: on the profiler's clock, in
 # the same trace as the device's ops, while a trace runs, and about a
 # microsecond each when none does.  ``engine.tick`` carries ``kind``
@@ -272,11 +275,18 @@ class _Slot:
 
 
 # each executable's function has its own name, which jit gives the module
-# (``jit_mixed_step``, ...), so a profiler trace says which step ran
+# (``jit_mixed_step``, ...), so a profiler trace says which step ran.  Every
+# step returns (next token, logits, cache[, greedy], finite): ``finite`` (B,)
+# says whether each row's logits are all finite, so the host reads one bool
+# a row for ``check_finite`` instead of copying the (B, vocab) logits.
+def _finite(logits):
+    return jnp.all(jnp.isfinite(logits), axis=-1)
+
+
 def _mixed_executable(cfg: ModelConfig):
     def mixed_step(p, c, tokens, lengths, q_lens):
         logits, new_c = api.mixed_step(cfg, p, c, tokens, lengths, q_lens)
-        return jnp.argmax(logits, axis=-1), logits, new_c
+        return jnp.argmax(logits, axis=-1), logits, new_c, _finite(logits)
     return jax.jit(mixed_step, donate_argnums=(1,))
 
 
@@ -284,7 +294,7 @@ def _mixed_executable_paged(cfg: ModelConfig):
     def mixed_step(p, c, tokens, lengths, q_lens, page_table):
         logits, new_c = api.mixed_step(cfg, p, c, tokens, lengths, q_lens,
                                        page_table=page_table)
-        return jnp.argmax(logits, axis=-1), logits, new_c
+        return jnp.argmax(logits, axis=-1), logits, new_c, _finite(logits)
     return jax.jit(mixed_step, donate_argnums=(1,))
 
 
@@ -310,14 +320,14 @@ def _mixed_executable_spec(cfg: ModelConfig, paged: bool):
         next_tok = jnp.take_along_axis(greedy, idx[:, None], axis=1)[:, 0]
         last_logits = jnp.take_along_axis(
             logits, idx[:, None, None], axis=1)[:, 0]
-        return next_tok, last_logits, new_c, greedy
+        return next_tok, last_logits, new_c, greedy, _finite(last_logits)
     return jax.jit(mixed_step_spec, donate_argnums=(1,))
 
 
 def _decode_executable(cfg: ModelConfig):
     def decode_step(p, c, tokens, lengths):
         logits, new_c = api.decode_step(cfg, p, c, tokens, lengths)
-        return jnp.argmax(logits, axis=-1), logits, new_c
+        return jnp.argmax(logits, axis=-1), logits, new_c, _finite(logits)
     return jax.jit(decode_step, donate_argnums=(1,))
 
 
@@ -329,7 +339,7 @@ def _decode_executable_paged(cfg: ModelConfig):
         logits, new_c = api.decode_step(cfg, p, c, tokens, lengths,
                                         page_table=page_table,
                                         write_mask=write_mask)
-        return jnp.argmax(logits, axis=-1), logits, new_c
+        return jnp.argmax(logits, axis=-1), logits, new_c, _finite(logits)
     return jax.jit(decode_step, donate_argnums=(1,))
 
 
@@ -495,6 +505,7 @@ class Engine:
         self.steps = 0
         self.dispatches = 0          # must equal steps: one dispatch per tick
         self.mixed_ticks = 0
+        self.logits_fetches = 0      # ticks that copied logits to the host
         # positions the step computed (batch x bucket width, or batch in a
         # decode tick) and those that advanced a row: their ratio sizes the
         # padding of the one-data-shape dispatch
@@ -507,9 +518,11 @@ class Engine:
         # behavior); a request at the bound is immune, so progress is
         # guaranteed.  enforce_deadlines sweeps deadline_s requests (queued
         # or running) every tick; check_finite quarantines rows whose logits
-        # go non-finite; audit_every=N self-checks allocator/page-table
-        # invariants every N ticks; chaos is a serving.chaos.ChaosMonkey
-        # injecting deterministic faults at exactly these seams.
+        # go non-finite (the step's per-row flag, computed on the device and
+        # fetched with the tokens: no logits copy); audit_every=N self-checks
+        # allocator/page-table invariants every N ticks; chaos is a
+        # serving.chaos.ChaosMonkey injecting deterministic faults at exactly
+        # these seams.
         self.max_preemptions = max_preemptions
         self.enforce_deadlines = enforce_deadlines
         self.check_finite = check_finite
@@ -1494,23 +1507,30 @@ class Engine:
                 len(drafts.get(i, ()))
                 for i in live))
 
-    def _fetch_logits(self, logits, live: list[int], chunks: list[int],
-                      decoding: list[int]) -> tuple[np.ndarray, set[int]]:
-        """Copy the tick's logits to the host for the ``sample`` hook and
-        the row checks; returns them with the rows whose logits are not
-        finite (``check_finite``; chaos may poison rows first)."""
-        logits_np = np.asarray(logits)
+    def _check_rows(self, finite_np: np.ndarray, logits, live: list[int],
+                    chunks: list[int], decoding: list[int],
+                    sample) -> tuple[np.ndarray | None, set[int]]:
+        """The tick's row checks: returns the logits on the host (for the
+        ``sample`` hook, else None) and the advancing rows whose logits are
+        not finite (``check_finite``).  Finiteness is read from the step's
+        per-row flag, computed on the device; a row chaos poisons this tick
+        is non-finite by construction.  So the (B, vocab) logits are copied
+        only for the hook, which sees the poisoned rows as NaN, and a
+        greedy tick copies nothing (``logits_fetches`` counts the copies)."""
         advancing = [i for i in live if chunks[i] or i in decoding]
-        if self.chaos is not None:
-            hit = self.chaos.corrupt_rows(advancing)
-            if hit:
-                logits_np = logits_np.copy()  # device arrays read-only
-                for i in hit:
-                    logits_np[i] = np.nan
+        hit = (self.chaos.corrupt_rows(advancing)
+               if self.chaos is not None else [])
+        logits_np = None
+        if sample is not None:
+            with TraceAnnotation("engine.fetch_logits"):
+                self.logits_fetches += 1
+                logits_np = np.asarray(logits)
+                if hit:
+                    logits_np = logits_np.copy()  # device arrays read-only
+                    logits_np[hit] = np.nan
         bad: set[int] = set()
         if self.check_finite:
-            bad = {i for i in advancing
-                   if not np.isfinite(logits_np[i]).all()}
+            bad = {i for i in advancing if not finite_np[i]}.union(hit)
         return logits_np, bad
 
     def _consume(self, live: list[int], chunks: list[int],
@@ -1657,23 +1677,20 @@ class Engine:
                         fn = self.cache_compiles.get("decode", self.batch,
                                                      self._build_decode)
                     if mixed and self.spec_k:
-                        next_tok, logits, self.cache, greedy = fn(
+                        next_tok, logits, self.cache, greedy, finite = fn(
                             self.params, self.cache, *args)
                     else:
-                        next_tok, logits, self.cache = fn(
+                        next_tok, logits, self.cache, finite = fn(
                             self.params, self.cache, *args)
                 self._count_tick(live, chunks, decoding, drafts, mixed, w,
                                  positions)
                 with TraceAnnotation("engine.sync"):
-                    next_np = np.asarray(next_tok)
-                    greedy_np = np.asarray(greedy) if drafts else None
-                logits_np = None
-                bad: set[int] = set()
-                if (sample is not None or self.check_finite or
-                        self.chaos is not None):
-                    with TraceAnnotation("engine.fetch_logits"):
-                        logits_np, bad = self._fetch_logits(
-                            logits, live, chunks, decoding)
+                    # one transfer: a token and a finiteness flag a row
+                    # (and the verify rows' greedy tokens)
+                    next_np, finite_np, greedy_np = jax.device_get(
+                        (next_tok, finite, greedy if drafts else None))
+                logits_np, bad = self._check_rows(finite_np, logits, live,
+                                                  chunks, decoding, sample)
                 # 3. consume: advance cursors, emit tokens, retire finished
                 # rows; faulted rows quarantine here, the rest are untouched
                 with TraceAnnotation("engine.consume"):

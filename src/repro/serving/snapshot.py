@@ -70,7 +70,7 @@ _JOURNAL_RE = re.compile(r"journal_(\d+)\.jsonl$")
 # engine counters that round-trip verbatim through the host manifest
 _COUNTERS = (
     "steps", "dispatches", "mixed_ticks", "_occupancy_sum",
-    "positions_computed", "positions_live",
+    "positions_computed", "positions_live", "logits_fetches",
     "peak_pool_blocks", "peak_resident_tokens", "admission_stalls",
     "prefix_hits", "prefix_hit_tokens", "cow_copies", "prefix_evictions",
     "preemptions", "deadline_misses", "row_faults", "cancels", "audits",

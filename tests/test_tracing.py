@@ -37,7 +37,7 @@ class FakeClock:
         return self.t
 
 
-def _two_rows(cfg, engine):
+def _two_rows(cfg, engine, sample=None):
     """Two rows of a 2-row engine with one 16-wide chunk bucket: a 5-token
     and a 20-token prompt, three new tokens each."""
     rng = np.random.default_rng(0)
@@ -46,7 +46,7 @@ def _two_rows(cfg, engine):
             for i, n in enumerate((5, 20))]
     for r in reqs:
         engine.submit(r)
-    assert engine.run().drained
+    assert engine.run(sample=sample).drained
     return reqs
 
 
@@ -77,7 +77,13 @@ def test_position_counters_equal_a_hand_count(setup):
     assert eng.positions_live == (5 + 16) + (1 + 4) + 2 + 1
 
 
-def test_engine_spans_nest_in_order_on_the_profiler_clock(setup, tmp_path):
+def _argmax(row):
+    return int(np.argmax(row))
+
+
+@pytest.mark.parametrize("sample", [None, _argmax], ids=["greedy", "hook"])
+def test_engine_spans_nest_in_order_on_the_profiler_clock(setup, tmp_path,
+                                                          sample):
     cfg, params = setup
     plain = Engine(cfg, params, batch_size=2, max_len=64, chunk_size=16)
     want = [r.output for r in _two_rows(cfg, plain)]
@@ -85,7 +91,7 @@ def test_engine_spans_nest_in_order_on_the_profiler_clock(setup, tmp_path):
                  compile_cache=plain.cache_compiles)
     jax.profiler.start_trace(str(tmp_path))
     try:
-        got = [r.output for r in _two_rows(cfg, eng)]
+        got = [r.output for r in _two_rows(cfg, eng, sample)]
     finally:
         jax.profiler.stop_trace()
     assert got == want              # an active profiler changes no token
@@ -106,10 +112,13 @@ def test_engine_spans_nest_in_order_on_the_profiler_clock(setup, tmp_path):
         if i == len(ticks) - 1:
             assert kids == ["engine.admit"]
             continue
-        # check_finite is on by default, and no journal is attached
+        # no journal is attached; the logits reach the host (under
+        # engine.fetch_logits) only for a sample hook: check_finite, on by
+        # default, reads the step's per-row flag with the tokens
+        copy = ["engine.fetch_logits"] if sample is not None else []
         assert kids == ["engine.admit", "engine.plan", "engine.prepare",
-                        "engine.dispatch", "engine.sync",
-                        "engine.fetch_logits", "engine.consume"]
+                        "engine.dispatch", "engine.sync", *copy,
+                        "engine.consume"]
         assert kids == [n for n in SPANS if n in kids]
 
 
