@@ -7,7 +7,10 @@ Run from the root of a checkout.  A cell is an entry of ``BENCHMARK.json``'s
 ``workloads``; it names a configuration (``configs/<name>.json``) and a
 traffic mix (``traffic/<name>.json``, generated by ``traffic/<kind>.py``),
 and its engine sizing and the limit of its check sit in
-``cells/<cell>.json``.  Each metric is read by ``metrics/<name>.py``.
+``cells/<cell>.json``.  The configuration names its model family, whose
+module ``families/<family>.py`` gives the weights' roles, the check of the
+program's sizes, the plain reference and the counts of work (see
+``families/dense.py``).  Each metric is read by ``metrics/<name>.py``.
 
 Set-up builds the served weights on the device from the seed, allocates
 the cell's paged KV pool (sized by ``size_pool.py`` from the chip's
@@ -38,6 +41,7 @@ import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import warnings  # noqa: E402
@@ -47,6 +51,11 @@ HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
 CACHE_DIR = ROOT / ".jax_cache"
 TRACE_DIR = ROOT / ".bench_trace"
+FAMILIES = HERE / "families"
+# what the harness takes from a family's module (families/dense.py)
+FAMILY_NEEDS = ("ROLES", "GENERATORS", "groups", "program_sizes", "logits",
+                "tick_flops", "tick_bytes", "tick_floor_s", "weight_bytes",
+                "kv_bytes_per_token")
 TRACE_S = 3.0          # traced seconds at the end of a --trace 1 window
 DRAIN_S = 60.0         # longest wait past the close for a first token
 
@@ -60,13 +69,15 @@ def load_cell(name: str) -> dict:
         raise SystemExit(f"unknown workload {name!r}; known: {sorted(cells)}")
     cell = cells[name]
     conf = {c["name"]: c for c in bench["configs"]}[cell["config"]]
+    config = json.loads((ROOT / conf["file"]).read_text())
 
     def applies(m):
         return "workloads" not in m or name in m["workloads"]
 
     return {
         "cell": cell,
-        "config": json.loads((ROOT / conf["file"]).read_text()),
+        "config": config,
+        "family": load_family(config),
         "traffic": json.loads(
             (HERE / "traffic" / f"{cell['traffic']}.json").read_text()),
         "sizing": json.loads((HERE / "cells" / f"{name}.json").read_text()),
@@ -81,6 +92,32 @@ def reader(name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def load_family(config: dict, directory: Path = FAMILIES):
+    """The module of the configuration's model family,
+    ``<directory>/<family>.py``; stops with a message where the
+    configuration names none, or one with no such file, or the file lacks
+    a name of ``FAMILY_NEEDS``."""
+    name = config.get("family")
+    known = sorted(f.stem for f in directory.glob("*.py"))
+    if name is None:
+        raise SystemExit(f"configuration {config['name']!r} names no model "
+                         f"family: give it \"family\", one of {known}")
+    path = directory / f"{name}.py"
+    if not re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.-]*", name) or \
+            not path.is_file():
+        raise SystemExit(f"configuration {config['name']!r} names model "
+                         f"family {name!r}, which has no file "
+                         f"{directory.name}/{name}.py; known: {known}")
+    spec = importlib.util.spec_from_file_location(f"family_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [n for n in FAMILY_NEEDS if not hasattr(mod, n)]
+    if missing:
+        raise SystemExit(f"model family {name!r} ({path.name}) lacks "
+                         f"{missing}")
+    return mod
 
 
 def peaks_for(kind: str) -> dict:
@@ -106,27 +143,24 @@ def require_chips(n: int):
 # the program under test
 # ---------------------------------------------------------------------------
 
-def program_config(config: dict, sizing: dict, pool_blocks: int = 0):
+def program_config(config: dict, family, pool_blocks: int = 0):
     """The program's ModelConfig for this configuration, checked against
-    the sizes the configuration states."""
+    the sizes the configuration states, over the keys of its family's
+    ``program_sizes``."""
     from repro.configs import get_config
 
     cfg = get_config(config["arch"], kv_layout="paged",
                      kv_pool_blocks=pool_blocks, **config["overrides"])
     m = config["model"]
-    have = {"n_layers": cfg.n_layers, "d_model": cfg.d_model,
-            "n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
-            "head_dim": cfg.head_dim, "d_ff": cfg.d_ff,
-            "vocab_size": cfg.vocab_size, "qkv_bias": cfg.qkv_bias,
-            "rope_theta": cfg.rope_theta}
-    wrong = {k: (v, m[k]) for k, v in have.items() if v != m[k]}
+    wrong = {k: (v, m.get(k)) for k, v in family.program_sizes(cfg).items()
+             if v != m.get(k)}
     if wrong:
         raise SystemExit(f"program config differs from {config['name']}: "
                          f"{wrong}")
     return cfg
 
 
-def build_weights(cfg, config: dict, key):
+def build_weights(cfg, config: dict, family, key):
     """The served, packed parameter tree, made on the device in one call."""
     import jax
 
@@ -139,7 +173,7 @@ def build_weights(cfg, config: dict, key):
     shapes = jax.eval_shape(lambda k: api.init_params(cfg, k),
                             jax.random.PRNGKey(0))
     build = jax.jit(lambda k: weights.build_served(
-        k, shapes, cfg.n_layers, served,
+        k, shapes, family, config["model"], served,
         lambda t: quantize_model(t, served["strategy"])))
     params = build(key)
     jax.block_until_ready(params)
@@ -267,7 +301,8 @@ def warm_up(engine, vocab: int) -> None:
 
 class Run:
     """What the metric readers read: the window's requests and ticks, the
-    trace's reduction, the engine's counters and the sizes."""
+    trace's reduction, the engine's counters, the sizes, and the model
+    family whose counts apply (``family``)."""
 
     def __init__(self, **kw):
         self.__dict__.update(kw)
@@ -337,17 +372,17 @@ def set_up(spec: dict, seed: int, dev) -> dict:
 
     import weights
 
-    config, sizing = spec["config"], spec["sizing"]
+    config, sizing, family = spec["config"], spec["sizing"], spec["family"]
     rows, max_len = sizing["rows"], sizing["max_len"]
     split = {}
     t = time.monotonic()
-    cfg = program_config(config, sizing)
+    cfg = program_config(config, family)
     key = weights.base_key(seed)
-    params = build_weights(cfg, config, key)
+    params = build_weights(cfg, config, family, key)
     split["weights_s"] = time.monotonic() - t
     t = time.monotonic()
     blocks = sizing["pool_blocks"]
-    cfg = program_config(config, sizing, pool_blocks=blocks)
+    cfg = program_config(config, family, pool_blocks=blocks)
     engine = Engine(cfg, params, batch_size=rows, max_len=max_len,
                     chunk_size=sizing["chunk"])
     jax.block_until_ready(engine.cache)
@@ -444,7 +479,7 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, dev,
     run = Run(ws=ws, we=we, te=te, seconds=seconds, requests=requests,
               ticks=drv.ticks, occupancy=counts["occupancy"], trace=summary,
               setup_s=setup_s, model=config["model"],
-              served=config["served"], peaks=peaks)
+              served=config["served"], family=spec["family"], peaks=peaks)
     ttft = run.ttfts()
     if ttft:
         log(json.dumps({"ttft": {
@@ -470,7 +505,8 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, dev,
                           seed, sizing["check_tokens"],
                           sizing["check_requests"])
     program_gap, control_gap, compared = check.gaps(
-        key, config["model"], config["served"], picked, control)
+        spec["family"], key, config["model"], config["served"], picked,
+        control)
     gap = control_gap if control else program_gap
     limit = sizing["gap_limit"]
     window = run.window_requests

@@ -2,11 +2,12 @@
 
 After the window, a sample of the requests the engine finished, drawn from
 the seed and always holding the longest of them, is run through the plain
-reference once, each prompt with the tokens the engine served after it.
-Each served token is greedy, so the reference's logit for it should sit at
-the top of that position's logits, short of rounding.  The number compared
-is the widest gap, over every served token of the sample, by which the
-served token's reference logit lies below the reference's best.
+reference of the configuration's model family (its ``logits``) once, each
+prompt with the tokens the engine served after it.  Each served token is
+greedy, so the reference's logit for it should sit at the top of that
+position's logits, short of rounding.  The number compared is the widest
+gap, over every served token of the sample, by which the served token's
+reference logit lies below the reference's best.
 
 The control runs the same reference in float8 in the program's place: at
 each of the same positions it reads the gap of the token that float8 puts
@@ -17,8 +18,6 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 import numpy as np
-
-import reference
 
 
 def sample(finished: list[dict], seed: int, tokens: int,
@@ -63,15 +62,15 @@ def widest_gap(ref_logits, chosen) -> float:
     return worst
 
 
-def gaps(key, model, served_spec, reqs, control: bool = False):
+def gaps(family, key, model, served_spec, reqs, control: bool = False):
     """(widest gap of the served tokens, widest gap of the tokens float8
     puts first at the same positions or None without ``control``, tokens
-    compared)."""
+    compared), by the reference of ``family``."""
     seqs, positions, served = inputs(reqs)
-    ref = reference.logits(key, model, served_spec, seqs, positions)
+    ref = family.logits(key, model, served_spec, seqs, positions)
     low = None
     if control:
-        fp8 = reference.logits(key, model, served_spec, seqs, positions,
-                               precision="fp8")
+        fp8 = family.logits(key, model, served_spec, seqs, positions,
+                            precision="fp8")
         low = widest_gap(ref, [jnp.argmax(lg, axis=-1) for lg in fp8])
     return widest_gap(ref, served), low, int(sum(len(s) for s in served))

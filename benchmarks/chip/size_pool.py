@@ -25,7 +25,7 @@ import sys
 import bench
 
 
-def step_bytes(cfg, config, sizing, blocks: int, dev) -> dict:
+def step_bytes(cfg, config, family, sizing, blocks: int, dev) -> dict:
     """The compiler's device bytes of each step at ``blocks``."""
     import jax
     import jax.numpy as jnp
@@ -45,7 +45,7 @@ def step_bytes(cfg, config, sizing, blocks: int, dev) -> dict:
     shapes = jax.eval_shape(lambda k: api.init_params(cfg, k),
                             jax.random.PRNGKey(0))
     params = jax.eval_shape(lambda k: weights.build_served(
-        k, shapes, cfg.n_layers, served,
+        k, shapes, family, config["model"], served,
         lambda t: quantize_model(t, served["strategy"])),
         jax.random.PRNGKey(0))
     cache = jax.eval_shape(lambda: api.init_cache(cfg, rows, max_len))
@@ -91,8 +91,8 @@ def main() -> int:
 
     jax.config.update("jax_compilation_cache_dir", str(bench.CACHE_DIR))
     dev = bench.require_chips(spec["cell"]["chips"])
-    config, sizing = spec["config"], spec["sizing"]
-    cfg = bench.program_config(config, sizing)
+    config, sizing, family = spec["config"], spec["sizing"], spec["family"]
+    cfg = bench.program_config(config, family)
     _, pages = paged_geometry(cfg, sizing["max_len"])
     full = sizing["rows"] * pages
     limit = dev.memory_stats()["bytes_limit"]
@@ -101,7 +101,7 @@ def main() -> int:
 
     def worst(p):
         if p not in seen:
-            seen[p] = step_bytes(cfg, config, sizing, p, dev)
+            seen[p] = step_bytes(cfg, config, family, sizing, p, dev)
         return max(s["total"] for s in seen[p].values())
 
     p0, p1 = sizing["rows"], 2 * sizing["rows"]

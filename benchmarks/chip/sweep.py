@@ -73,7 +73,8 @@ def main() -> int:
                         requests=reqs, ticks=ticks, occupancy=counts["occupancy"],
                         trace=None, setup_s=0.0,
                         model=spec["config"]["model"],
-                        served=spec["config"]["served"], peaks=peaks)
+                        served=spec["config"]["served"],
+                        family=spec["family"], peaks=peaks)
         metrics = {m: bench.reader(m)(run) for m in
                    ("ttft_p95_s", "itl_p50_ms", "itl_p95_ms", "output_tok_s",
                     "queue_wait_p95_s", "slot_occupancy", "tick_ms.decode",

@@ -44,11 +44,11 @@ def test_weight_bytes_match_the_program_packing():
 
     spec = tiny.spec(1.0)
     import bench
-    cfg = bench.program_config(spec["config"], spec["sizing"])
+    cfg = bench.program_config(spec["config"], spec["family"])
     shapes = jax.eval_shape(lambda k: api.init_params(cfg, k),
                             jax.random.PRNGKey(0))
     packed = jax.eval_shape(lambda k: weights.build_served(
-        k, shapes, cfg.n_layers, SERVED,
+        k, shapes, spec["family"], spec["config"]["model"], SERVED,
         lambda t: quantize_model(t, SERVED["strategy"])),
         jax.random.PRNGKey(0))
     assert quantized_bytes(packed) == counts.weight_bytes(

@@ -4,6 +4,8 @@ import copy
 import json
 from pathlib import Path
 
+from bench import load_family
+
 CHIP = Path(__file__).resolve().parents[1]
 
 SIZES = {"n_layers": 4, "d_model": 256, "n_heads": 2, "n_kv_heads": 2,
@@ -28,6 +30,7 @@ def spec(gap_limit: float, kind: str = "poisson") -> dict:
     return {
         "cell": {"name": "tiny", "chips": 1},
         "config": config,
+        "family": load_family(config),
         "traffic": traffic,
         "sizing": {"rows": 4, "max_len": 64, "chunk": 16, "pool_blocks": 16,
                    "check_tokens": 24,

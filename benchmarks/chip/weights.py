@@ -28,7 +28,8 @@ import jax.numpy as jnp
 BLOCK = 128
 _Q_STD = math.sqrt((15 ** 2 - 1) / 12)     # std of a uniform int in [-7, 7]
 
-# The program's parameter leaves, by path, and the role each plays.
+# The dense decoder's parameter leaves, by path, and the role each plays
+# (families/dense.py; another family brings its own).
 ROLES = {
     "embed": "embed",
     "lm_head": "lm_head",
@@ -131,36 +132,61 @@ def path_str(path) -> str:
     return "/".join(str(getattr(e, "key", e)) for e in path)
 
 
-def layer_leaf(key, path: str, layer, shape: tuple, served: dict):
-    """Leaf ``path`` of layer ``layer`` (0 for unstacked leaves)."""
-    if path not in ROLES:
+def layer_leaf(key, path: str, layer, shape: tuple, served: dict,
+               roles: dict = ROLES, generators: dict | None = None):
+    """Leaf ``path`` of layer ``layer`` (0 for unstacked leaves).  ``roles``
+    maps each parameter path to its role (the dense decoder's by default);
+    a role in ``generators`` is made by that function of ``(key, shape,
+    served)``, any other by ``role_leaf``."""
+    if path not in roles:
         raise KeyError(f"parameter leaf {path!r} has no role in the "
                        "benchmark's generator")
-    return role_leaf(leaf_key(key, path, layer), ROLES[path], shape, served)
+    key = leaf_key(key, path, layer)
+    if generators and roles[path] in generators:
+        return generators[roles[path]](key, shape, served)
+    return role_leaf(key, roles[path], shape, served)
 
 
-def build_served(key, shapes, n_layers: int, served: dict, pack):
+def per_expert(key, n: int, make) -> jax.Array:
+    """A leaf stacked over ``n`` experts: expert ``e``'s slice is
+    ``make(k)`` with ``e`` folded into ``key``, made one expert at a time."""
+    return jax.lax.map(lambda e: make(jax.random.fold_in(key, e)),
+                       jnp.arange(n, dtype=jnp.int32))
+
+
+def build_served(key, shapes, family, model: dict, served: dict, pack):
     """The served parameter tree: every leaf of ``shapes`` (the program's
-    ``eval_shape`` of its initialiser) made from ``key``, and the stacked
-    layer leaves made and packed one layer at a time (``lax.map``), all in
-    the one traced function this is called from.  ``pack`` is the program's
-    packing of a parameter tree."""
+    ``eval_shape`` of its initialiser) made from ``key`` by the roles of
+    ``family`` (its ``ROLES`` and ``GENERATORS``), and the leaves of each of
+    its stacked groups of layers (``family.groups(model)``: a top-level key
+    of the tree and its layer count) made and packed one layer at a time
+    (``lax.map``), all in the one traced function this is called from.
+    ``pack`` is the program's packing of a parameter tree."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     paths = [path_str(p) for p, _ in flat]
-    stacked = {p: s for p, (_, s) in zip(paths, flat)
-               if p.startswith("blocks/")}
-    top = {p: s for p, (_, s) in zip(paths, flat) if p not in stacked}
+    groups = family.groups(model)
 
-    def one_layer(layer):
-        tree = {p: layer_leaf(key, p, layer, s.shape[1:], served)
-                for p, s in stacked.items()}
-        return pack(_nest(tree))
+    def leaf(p, layer, shape):
+        return layer_leaf(key, p, layer, shape, served, family.ROLES,
+                          family.GENERATORS)
 
-    layers = jax.lax.map(one_layer, jnp.arange(n_layers, dtype=jnp.int32))
-    rest = pack(_nest({p: layer_leaf(key, p, 0, s.shape, served)
-                       for p, s in top.items()}))
-    out = dict(rest)
-    out["blocks"] = layers["blocks"]
+    def group(p):
+        head = p.split("/", 1)[0]
+        return head if head in groups else None
+
+    out = {}
+    for g, n_layers in groups.items():
+        stacked = {p: s for p, (_, s) in zip(paths, flat) if group(p) == g}
+
+        def one_layer(layer, stacked=stacked):
+            return pack(_nest({p: leaf(p, layer, s.shape[1:])
+                               for p, s in stacked.items()}))
+
+        out[g] = jax.lax.map(one_layer, jnp.arange(n_layers,
+                                                   dtype=jnp.int32))[g]
+    rest = pack(_nest({p: leaf(p, 0, s.shape)
+                       for p, (_, s) in zip(paths, flat) if group(p) is None}))
+    out = {**rest, **out}
     leaves = []
     for p in paths:
         node = out
